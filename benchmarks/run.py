@@ -227,21 +227,37 @@ def write_summary(results_dir: str = RESULTS,
     return records
 
 
-def _subprocess_bench(module: str):
-    """Isolate 8-fake-device benchmarks in a fresh interpreter: the device
-    count must be forced before JAX backend init, which must not re-size the
-    backend the other benchmarks run (and time) on."""
+# job name -> benchmarks module with a ``run(quick=...)`` entry point. Every
+# job runs in its own child interpreter and this parent never imports JAX:
+# an accelerator belongs to the one process that touched JAX first, so a
+# parent holding it would leave its children without a device. The child
+# boundary also lets the distributed benches force their 8 fake host devices
+# before backend init without resizing any other job's backend.
+_JOBS = {
+    "fig1a_correlation": "bench_fig1a_correlation",
+    "fig1b_mask_vs_sketch": "bench_fig1b_mask_vs_sketch",
+    "fig2a_proxies": "bench_fig2a_proxies",
+    "fig2b_spectral": "bench_fig2b_spectral",
+    "fig3_larger_archs": "bench_fig3_larger_archs",
+    "fig4_location": "bench_fig4_location",
+    "variance_eq6": "bench_variance",
+    "cost_backends": "bench_cost",
+    "block_granularity": "bench_block_granularity",
+    "adaptive": "bench_adaptive",
+    "coverage": "bench_coverage",
+    "resilience": "bench_resilience",
+    "serve": "bench_serve",
+    "obs": "bench_obs",
+    "distributed": "bench_distributed",
+    "backward_fusion": "bench_backward_fusion",
+}
 
-    def run(quick: bool = True):
-        r = subprocess.run([sys.executable, "-m", module], text=True)
-        if r.returncode != 0:
-            raise RuntimeError(f"{module} exited {r.returncode}")
 
-    return run
-
-
-_run_distributed = _subprocess_bench("benchmarks.bench_distributed")
-_run_backward_fusion = _subprocess_bench("benchmarks.bench_backward_fusion")
+def _run_job(module: str, quick: bool) -> None:
+    code = f"from benchmarks import {module} as m; m.run(quick={quick})"
+    r = subprocess.run([sys.executable, "-c", code], cwd=ROOT, text=True)
+    if r.returncode != 0:
+        raise RuntimeError(f"{module} exited {r.returncode}")
 
 
 def main():
@@ -264,41 +280,16 @@ def main():
               f"{len(failures)} regression(s)")
         raise SystemExit(1 if failures else 0)
 
-    from benchmarks import (bench_adaptive, bench_block_granularity,
-                            bench_cost, bench_coverage,
-                            bench_fig1a_correlation, bench_fig1b_mask_vs_sketch,
-                            bench_fig2a_proxies, bench_fig2b_spectral,
-                            bench_fig3_larger_archs, bench_fig4_location,
-                            bench_obs, bench_resilience, bench_serve,
-                            bench_variance)
-    jobs = {
-        "fig1a_correlation": bench_fig1a_correlation.run,
-        "fig1b_mask_vs_sketch": bench_fig1b_mask_vs_sketch.run,
-        "fig2a_proxies": bench_fig2a_proxies.run,
-        "fig2b_spectral": bench_fig2b_spectral.run,
-        "fig3_larger_archs": bench_fig3_larger_archs.run,
-        "fig4_location": bench_fig4_location.run,
-        "variance_eq6": bench_variance.run,
-        "cost_backends": bench_cost.run,
-        "block_granularity": bench_block_granularity.run,
-        "adaptive": bench_adaptive.run,
-        "coverage": bench_coverage.run,
-        "resilience": bench_resilience.run,
-        "serve": bench_serve.run,
-        "obs": bench_obs.run,
-        "distributed": _run_distributed,
-        "backward_fusion": _run_backward_fusion,
-    }
     failures = 0
-    for name, fn in jobs.items():
+    for name, module in _JOBS.items():
         if args.only and args.only != name:
             continue
         print(f"\n===== {name} =====", flush=True)
         t0 = time.time()
         try:
-            fn(quick=quick)
+            _run_job(module, quick)
             print(f"[{name}] done in {time.time()-t0:.1f}s")
-        except Exception:
+        except RuntimeError:
             failures += 1
             traceback.print_exc()
             print(f"[{name}] FAILED")

@@ -14,6 +14,7 @@ by default), async checkpointing + auto-resume, and a budget schedule
 """
 import argparse
 
+from repro import compat
 from repro.api import (BudgetSchedule, ExecutionConfig, Runtime, SketchConfig,
                        SketchPolicy, TelemetryConfig)
 from repro.configs.base import ArchConfig
@@ -54,6 +55,7 @@ def main():
     ap.add_argument("--telemetry-jsonl", default=None,
                     help="write per-step telemetry records to this JSONL file")
     args = ap.parse_args()
+    print(f"compile cache: {compat.enable_compilation_cache()}")
 
     cfg = arch_100m(args.tiny)
     policy = None if args.exact else SketchPolicy(

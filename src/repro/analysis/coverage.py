@@ -8,7 +8,7 @@ no FLOP is spent, no state is touched), then answers, per weight leaf:
 
 Mechanics (validated against every registered arch family):
 
-* **Flattened provenance graph** — ``pjit`` / ``remat2`` /
+* **Flattened provenance graph** — ``jit`` / ``remat2`` /
   ``custom_vjp_call_jaxpr`` sub-jaxprs are inlined into one global var
   graph (loop primitives stay opaque; under ``cost_mode`` ctx the chunk
   scans are python-unrolled so almost nothing hides in a loop body).
@@ -73,7 +73,7 @@ _TRANSPARENT = frozenset({
 })
 
 # Straight-line higher-order primitives inlined into the flat graph.
-_INLINE = frozenset({"pjit", "remat2", "custom_vjp_call_jaxpr",
+_INLINE = frozenset({"jit", "remat2", "custom_vjp_call_jaxpr",
                      "custom_jvp_call", "custom_vjp_call", "closed_call",
                      "checkpoint"})
 
@@ -195,9 +195,9 @@ class _Graph:
     """Flattened producer graph over a closed jaxpr (see module docstring)."""
 
     def __init__(self, closed_jaxpr):
-        import jax
+        from jax.extend.core import Literal
 
-        self._literal = jax.core.Literal
+        self._literal = Literal
         self.eqns: List[Tuple[object, dict]] = []   # (eqn, invar-substitution)
         self.alias: Dict[object, object] = {}       # outer var -> inner var
         self.dots: List[Tuple[object, float]] = []  # every dot, x trip count

@@ -161,15 +161,12 @@ def _version_gated(path: str) -> Optional[str]:
     if not _jax_rooted(path):
         return None
     if path.split(".")[-1] == "AxisType":
-        return "jax.sharding.AxisType is absent on part of the supported range"
+        return "jax.sharding.AxisType arrived late and changed across releases"
     if path == "jax.shard_map" or ".experimental.shard_map" in path \
             or path.endswith(".shard_map"):
-        return "shard_map moved modules across the supported range"
+        return "shard_map moved modules and renamed its check kwarg"
     if path == "jax.make_mesh":
-        return "jax.make_mesh is absent on part of the supported range"
-    if path == "jax.lax.optimization_barrier":
-        return ("optimization_barrier ships without a vmap batching rule on "
-                "some releases")
+        return "jax.make_mesh changed signature across releases"
     return None
 
 
@@ -180,7 +177,7 @@ _GATED_KWARGS = ("axis_types", "check_vma", "check_rep")
 class JaxVersionGatedRule:
     id: str = "jax-version-gated"
     description: str = ("version-gated JAX symbol used outside repro/compat.py "
-                        "(AxisType, shard_map, make_mesh, optimization_barrier, "
+                        "(AxisType, shard_map, make_mesh, "
                         "axis_types=/check_vma=/check_rep=)")
     allow: Tuple[str, ...] = ("compat.py",)
 

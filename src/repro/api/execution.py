@@ -56,7 +56,8 @@ class ExecutionConfig:
         backward kernels' resident accumulators — above it the dispatch
         drops to the one-gather XLA fallback (``repro.kernels.ops``).
         ``None`` (the default) defers to the ``REPRO_FUSED_VMEM_LIMIT`` env
-        var, then the built-in ~12 MiB headroom default. Steps built from
+        var, then the built-in 16 MiB (the v5e compiler's default scoped-VMEM
+        limit, which the estimates are calibrated against). Steps built from
         this config bind the value (and the obs metrics registry, which
         records every dispatch/fallback decision) via
         ``kernels.ops.configure``. See docs/perf.md.

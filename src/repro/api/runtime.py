@@ -124,6 +124,9 @@ def _with_ledger(jfn, ob, lkey: str, want_memory: bool):
         state["compiled"] = compiled
         return compiled(*args, **kw)
 
+    # the executable the calls run (None before the first call or after a
+    # fallback), for callers that inspect the program that actually ran
+    step.compiled = lambda: state["compiled"]
     return step
 
 

@@ -1,35 +1,21 @@
-"""Version-portability layer for JAX API drift.
+"""One home for the JAX surfaces that changed across releases.
 
-Supported range: jax 0.4.26+ through the 0.7 line (see docs/distributed.md).
-All drift handling is feature-detected, never version-compared.
-
-Surfaces that genuinely break somewhere inside that range are centralized
-here, and no other module may reference them directly — enforced
-symbol-by-symbol by
+Written for the installed JAX, 0.9.0 (see docs/distributed.md). The
+surfaces below moved or were renamed in earlier releases, so no other
+module may reference them directly — enforced symbol-by-symbol by
 tests/test_compat.py::test_no_version_gated_jax_symbols_outside_compat:
 
-  * mesh construction — ``jax.make_mesh`` grew an ``axis_types`` kwarg
-    (``jax.sharding.AxisType``) in newer releases; older releases predate
-    ``jax.make_mesh`` entirely and build ``Mesh(mesh_utils.create_device_mesh)``
-  * ``shard_map`` — moved from ``jax.experimental.shard_map`` to ``jax.shard_map``,
-    and its replication-check kwarg was renamed ``check_rep`` → ``check_vma``
-  * ``jax.tree_util.register_dataclass`` — absent on older releases, and its
-    early versions require explicit field lists (bare decorator came later)
+  * mesh construction — ``jax.make_mesh`` with explicit ``axis_types``
+    (``jax.sharding.AxisType``); the repo assumes Auto axes throughout
+  * ``jax.shard_map`` and its replication-check kwarg ``check_vma``
 
-The pytree (``jax.tree.*``) and typed-PRNG-key (``jax.random.key``) helpers
-below are *stable within the supported range*; they exist for uniform use by
-the distributed stack and as best-effort cover below the 0.4.26 floor (where
-``jax.tree`` / typed keys are missing), not as enforced gates — modules
-outside the distributed stack may call ``jax.tree.*`` directly.
+The rest are thin shared helpers (pytree ops, typed PRNG keys, jaxpr source
+provenance, profiler annotations, the persistent compilation cache) kept
+here so an upgrade touches one file.
 """
 from __future__ import annotations
 
-import contextlib
-import dataclasses
-import inspect
 import os
-import warnings
-from typing import Any, Optional
 
 import jax
 
@@ -38,7 +24,6 @@ __all__ = [
     "shard_map",
     "ensure_host_devices",
     "enable_compilation_cache",
-    "optimization_barrier",
     "prng_key",
     "key_dtype",
     "tree_map",
@@ -60,32 +45,12 @@ __all__ = [
 # ---------------------------------------------------------------------------
 
 
-def _axis_types_kw(n_axes: int) -> dict:
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n_axes}
-
-
 def make_mesh(shape, axes, *, devices=None):
-    """Build a ``jax.sharding.Mesh`` on any supported JAX.
-
-    Newer JAX distinguishes Auto/Explicit mesh axes; we always request Auto
-    (the pjit-style GSPMD behaviour the whole repo assumes). Older JAX has no
-    axis types — plain meshes behave identically.
-    """
-    shape = tuple(shape)
+    """Build a ``jax.sharding.Mesh`` whose axes are all Auto (the GSPMD
+    behaviour the whole repo assumes)."""
     axes = tuple(axes)
-    if hasattr(jax, "make_mesh"):
-        try:
-            return jax.make_mesh(shape, axes, devices=devices,
-                                 **_axis_types_kw(len(axes)))
-        except TypeError:
-            return jax.make_mesh(shape, axes, devices=devices)
-    from jax.experimental import mesh_utils
-
-    devs = mesh_utils.create_device_mesh(shape, devices=devices)
-    return jax.sharding.Mesh(devs, axes)
+    return jax.make_mesh(tuple(shape), axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def ensure_host_devices(n: int) -> None:
@@ -104,47 +69,26 @@ def ensure_host_devices(n: int) -> None:
         flags + f" --xla_force_host_platform_device_count={n}").strip()
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None, *,
-                             min_compile_time_secs: Optional[float] = None) -> bool:
-    """Turn on JAX's persistent compilation cache, where this release has it.
+# <checkout>/.jax_cache (gitignored): src/repro/compat.py -> checkout root
+CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
+    ".jax_cache")
 
-    Feature-detected (``jax.config.update`` raises on unknown options —
-    absence degrades to a no-op returning False, never a version compare).
-    An explicitly configured cache (``JAX_COMPILATION_CACHE_DIR`` env or a
-    prior call) is left alone.
 
-    ``min_compile_time_secs=None`` keeps JAX's own threshold (~1 s), which
-    caches exactly the expensive compiles worth persisting. Do NOT lower it
-    to cache everything: serializing the long tail of sub-second executables
-    costs more wall-clock than it saves.
+def enable_compilation_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    jax 0.4.37 (jaxlib 0.4.36) CPU is blacklisted outright: an executable
-    *reloaded* from the persistent cache loses its input-output aliasing
-    metadata, so donated state chains free buffers that are still alive —
-    recycled bytes in donated outputs at best, ``malloc_consolidate():
-    invalid chunk size`` at worst. Reproduce by running the resilience
-    drill twice against a warm cache. This is a version blacklist rather
-    than the usual feature detection because the breakage is silent memory
-    corruption — there is nothing to probe without tripping it.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX has already read it and
+    nothing is set here. Otherwise the cache lives at :data:`CACHE_DIR`, one
+    fixed directory inside the checkout: the directory is part of the cache
+    key, so a path made from a temp name, a pid or the time would never hit.
+    JAX's own minimum compile time (about 1 s) decides what is cached.
     """
-    if jax.default_backend() == "cpu" and jax.__version__ == "0.4.37":
-        return False
-    if cache_dir is None:
-        if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
-            return True  # explicitly configured — respect it
-        import tempfile
-        cache_dir = os.path.join(tempfile.gettempdir(), "repro-jax-cache")
-    try:
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-    except (AttributeError, ValueError, TypeError):
-        return False  # release predates the persistent cache
-    if min_compile_time_secs is not None:
-        try:
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              min_compile_time_secs)
-        except (AttributeError, ValueError, TypeError):
-            pass  # threshold is tuning, not a requirement
-    return True
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
+    return CACHE_DIR
 
 
 # ---------------------------------------------------------------------------
@@ -152,78 +96,12 @@ def enable_compilation_cache(cache_dir: Optional[str] = None, *,
 # ---------------------------------------------------------------------------
 
 
-def _resolve_shard_map():
-    fn = getattr(jax, "shard_map", None)
-    if fn is None:
-        from jax.experimental.shard_map import shard_map as fn  # type: ignore
-    params = inspect.signature(fn).parameters
-    if "check_vma" in params:
-        flag = "check_vma"
-    elif "check_rep" in params:
-        flag = "check_rep"
-    else:
-        flag = None
-    return fn, flag
-
-
-_SHARD_MAP, _SM_CHECK_FLAG = _resolve_shard_map()
-
-
 def shard_map(f, *, mesh, in_specs, out_specs, check: bool = False):
-    """Portable ``shard_map``.
-
-    ``check`` maps onto ``check_vma`` (new) / ``check_rep`` (old). The repo
-    default is False: our bodies mix psum/psum_scatter over axis subsets in
-    ways the replication checker rejects on several releases.
-    """
-    kw = {_SM_CHECK_FLAG: check} if _SM_CHECK_FLAG else {}
-    return _SHARD_MAP(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-
-
-# ---------------------------------------------------------------------------
-# optimization_barrier
-# ---------------------------------------------------------------------------
-
-_OPT_BARRIER_PATCHED = False
-
-
-def _ensure_barrier_batchable() -> None:
-    """Backfill the vmap rule for ``optimization_barrier``.
-
-    The primitive exists throughout the supported range, but releases in it
-    (e.g. 0.4.37) ship it without a batching rule, so any ``vmap``/``lax.map
-    (batch_size=...)`` over code using a barrier raises NotImplementedError
-    (fixed upstream later). The rule is the identity passthrough. Failure to
-    patch degrades gracefully — the barrier only guards against fusion
-    duplication, not correctness."""
-    global _OPT_BARRIER_PATCHED
-    if _OPT_BARRIER_PATCHED:
-        return
-    _OPT_BARRIER_PATCHED = True
-    try:
-        from jax._src.lax import lax as _lax_internal
-        from jax.interpreters import batching
-
-        prim = _lax_internal.optimization_barrier_p
-        if prim not in batching.primitive_batchers:
-            def _rule(args, dims):
-                return prim.bind(*args), dims
-
-            batching.primitive_batchers[prim] = _rule
-    except Exception as e:  # pragma: no cover - private path moved
-        # degraded, not broken: the barrier still works outside vmap — but
-        # say so instead of failing silently on the next vmap'd barrier
-        warnings.warn(
-            f"could not backfill the optimization_barrier batching rule "
-            f"({type(e).__name__}: {e}); vmap over barrier-guarded code may "
-            f"raise NotImplementedError on this JAX release", stacklevel=2)
-
-
-def optimization_barrier(values):
-    """``jax.lax.optimization_barrier`` usable under vmap on every supported
-    release (see ``_ensure_barrier_batchable``)."""
-    _ensure_barrier_batchable()
-    return jax.lax.optimization_barrier(values)
+    """``jax.shard_map`` with the replication check off by default: our
+    bodies mix psum/psum_scatter over axis subsets in ways the checker
+    rejects."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+                         check_vma=check)
 
 
 # ---------------------------------------------------------------------------
@@ -232,10 +110,8 @@ def optimization_barrier(values):
 
 
 def prng_key(seed: int) -> jax.Array:
-    """Typed PRNG key where available, legacy uint32 key otherwise."""
-    if hasattr(jax.random, "key"):
-        return jax.random.key(seed)
-    return jax.random.PRNGKey(seed)
+    """Typed PRNG key."""
+    return jax.random.key(seed)
 
 
 def key_dtype():
@@ -247,16 +123,13 @@ def user_frames(source_info):
     """User-code (file_name, start_line) frames of one jaxpr equation.
 
     ``eqn.source_info`` provenance lives in ``jax._src.source_info_util``,
-    which is internal and has moved across releases — every consumer (the
-    sketch-coverage analyzer) goes through here so absence degrades to "no
-    provenance" instead of an ImportError.
+    which is internal; every consumer (the sketch-coverage analyzer) goes
+    through here.
     """
-    try:
-        from jax._src import source_info_util as siu
-        return [(f.file_name, f.start_line)
-                for f in siu.user_frames(source_info)]
-    except Exception:
-        return []
+    from jax._src import source_info_util as siu
+
+    return [(f.file_name, f.start_line)
+            for f in siu.user_frames(source_info.traceback)]
 
 
 # ---------------------------------------------------------------------------
@@ -265,77 +138,26 @@ def user_frames(source_info):
 
 
 def named_scope(name: str):
-    """``jax.named_scope`` context manager, or a null context where absent.
-
-    Purely a tracing-time op-naming aid (shows up in HLO / jaxpr dumps);
-    absence degrades to nothing.
-    """
-    fn = getattr(jax, "named_scope", None)
-    return fn(name) if fn is not None else contextlib.nullcontext()
-
-
-def _resolve_trace_annotation():
-    try:
-        return getattr(jax.profiler, "TraceAnnotation", None)
-    except AttributeError:  # pragma: no cover - profiler module absent
-        return None
-
-
-_TRACE_ANNOTATION = _resolve_trace_annotation()
+    """``jax.named_scope``: a tracing-time op-naming aid (HLO / jaxpr dumps)."""
+    return jax.named_scope(name)
 
 
 def trace_annotation(name: str):
-    """``jax.profiler.TraceAnnotation`` context manager when this release
-    has one, else a null context — host-side spans opened through it appear
-    on the TraceMe timeline of a real ``jax.profiler`` capture (negligible
-    cost outside an active profiling session)."""
-    if _TRACE_ANNOTATION is None:  # pragma: no cover - whole range has it
-        return contextlib.nullcontext()
-    return _TRACE_ANNOTATION(name)
+    """``jax.profiler.TraceAnnotation``: host-side spans opened through it
+    appear on the timeline of a ``jax.profiler`` capture (negligible cost
+    outside an active profiling session)."""
+    return jax.profiler.TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------
 # pytree ops
 # ---------------------------------------------------------------------------
 
-if hasattr(jax, "tree"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-    tree_structure = jax.tree.structure
-else:  # pre-jax.tree releases
-    tree_map = jax.tree_util.tree_map
-    tree_leaves = jax.tree_util.tree_leaves
-    tree_flatten = jax.tree_util.tree_flatten
-    tree_unflatten = jax.tree_util.tree_unflatten
-    tree_structure = jax.tree_util.tree_structure
-
+tree_map = jax.tree.map
+tree_leaves = jax.tree.leaves
+tree_flatten = jax.tree.flatten
+tree_unflatten = jax.tree.unflatten
+tree_structure = jax.tree.structure
 tree_map_with_path = jax.tree_util.tree_map_with_path
 tree_flatten_with_path = jax.tree_util.tree_flatten_with_path
-
-
-def register_dataclass(cls):
-    """``jax.tree_util.register_dataclass`` with a manual fallback.
-
-    Early releases of ``register_dataclass`` require explicit
-    ``data_fields``/``meta_fields`` (bare-decorator field inference came
-    later), so a bare call can raise TypeError even where the symbol exists —
-    both absence and that signature fall through to manual registration.
-    """
-    if hasattr(jax.tree_util, "register_dataclass"):
-        try:
-            return jax.tree_util.register_dataclass(cls)
-        except TypeError:
-            pass
-
-    fields = [f.name for f in dataclasses.fields(cls)]
-
-    def flatten(obj):
-        return tuple(getattr(obj, f) for f in fields), None
-
-    def unflatten(_, children):
-        return cls(**dict(zip(fields, children)))
-
-    jax.tree_util.register_pytree_node(cls, flatten, unflatten)
-    return cls
+register_dataclass = jax.tree_util.register_dataclass

@@ -29,6 +29,14 @@ class LMStream:
         self._succ = rng.integers(0, self.vocab, size=(self.vocab,), dtype=np.int32)
         w = (np.arange(1, self.vocab + 1, dtype=np.float64)) ** (-self.alpha)
         self._p = w / w.sum()
+        # the CDF rng.choice(vocab, p=_p) would rebuild on every call: the
+        # same draws (searchsorted of one uniform each) without an O(vocab)
+        # cumsum per token, which at a 262k vocab dominated batch making
+        cdf = self._p.cumsum()
+        self._cdf = cdf / cdf[-1]
+
+    def _draw(self, rng, n: int) -> np.ndarray:
+        return self._cdf.searchsorted(rng.random(n), side="right")
 
     def batches(self, batch: int, seq: int, *, start_step: int = 0, p_bigram: float = 0.8):
         """Infinite iterator of {tokens, labels} (labels = next token)."""
@@ -36,10 +44,10 @@ class LMStream:
         while True:
             rng = np.random.default_rng((self.seed, step))
             toks = np.empty((batch, seq + 1), np.int32)
-            toks[:, 0] = rng.choice(self.vocab, size=batch, p=self._p)
+            toks[:, 0] = self._draw(rng, batch)
             for t in range(seq):
                 follow = rng.random(batch) < p_bigram
-                rand = rng.choice(self.vocab, size=batch, p=self._p)
+                rand = self._draw(rng, batch)
                 toks[:, t + 1] = np.where(follow, self._succ[toks[:, t]], rand)
             yield {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
             step += 1

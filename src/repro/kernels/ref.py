@@ -85,13 +85,12 @@ def _gather_scaled_blocks(G, block_idx, scales, block: int, *,
     The optimization barrier pins the raw gather as a materialised buffer:
     without it XLA re-fuses the gather into every consumer, turning one HBM
     pass over kept G into one pass per consumer."""
-    from repro import compat
 
     cols = (block_idx[:, None] * block
             + jnp.arange(block, dtype=block_idx.dtype)[None, :]).reshape(-1)
     col_scales = jnp.repeat(scales, block)
     Gc0 = jnp.take(G, cols, axis=1).astype(jnp.float32)
-    (Gc0,) = compat.optimization_barrier((Gc0,))
+    (Gc0,) = jax.lax.optimization_barrier((Gc0,))
     kept_scores = None
     if score_mode is not None:
         kept_scores = jnp.sum(COL_SCORE_MODES[score_mode](Gc0), axis=0)
@@ -172,7 +171,6 @@ def block_stream_matmul_onepass_ref(G, block_idx, scales, W, X, *, block: int,
     the scores anyway); vs the two-pass path the separate score read of G is
     gone. Shapes: dX [N, d], dWc [rb, block, d], db_c [rb, block] f32,
     scores [n] f32 (raw Σ|G| or ΣG² per column)."""
-    from repro import compat
 
     N, n = G.shape
     nb = n // block
@@ -181,7 +179,7 @@ def block_stream_matmul_onepass_ref(G, block_idx, scales, W, X, *, block: int,
     cols = (perm[:, None] * block
             + jnp.arange(block, dtype=jnp.int32)[None, :]).reshape(-1)
     Gall = jnp.take(G, cols, axis=1).astype(jnp.float32)
-    (Gall,) = compat.optimization_barrier((Gall,))
+    (Gall,) = jax.lax.optimization_barrier((Gall,))
     red = jnp.sum(COL_SCORE_MODES[score_mode](Gall), axis=0)  # [n] permuted
     scores = jnp.zeros((n,), jnp.float32).at[cols].set(red)
     kept = rb * block
@@ -197,13 +195,12 @@ def gather_cols_onepass_ref(G, idx, scales, W, X, *, score_mode: str = "l1"):
     with ONE reader of G — the unblocked counterpart of
     :func:`block_stream_matmul_onepass_ref`. dW_rows: [r, d_in]; db_rows:
     [r] f32; scores: [n] f32 raw per-column reduction."""
-    from repro import compat
 
     n = G.shape[1]
     r = idx.shape[0]
     perm = _onepass_perm(idx.astype(jnp.int32), n, r)
     Gall = jnp.take(G, perm, axis=1).astype(jnp.float32)
-    (Gall,) = compat.optimization_barrier((Gall,))
+    (Gall,) = jax.lax.optimization_barrier((Gall,))
     red = jnp.sum(COL_SCORE_MODES[score_mode](Gall), axis=0)
     scores = jnp.zeros((n,), jnp.float32).at[perm].set(red)
     Gc = Gall[:, :r] * scales[None, :].astype(jnp.float32)
@@ -221,11 +218,10 @@ def gather_cols_fused_scores_ref(G, idx, scales, W, X, *,
     barriered gather of G: (dX, dW_rows, db_rows, kept_scores). The stale
     estimator's unblocked path — like the per-column compact pair but the
     gather is shared and the raw reduction rides along for free."""
-    from repro import compat
 
     r = idx.shape[0]
     Gc0 = jnp.take(G, idx, axis=1).astype(jnp.float32)
-    (Gc0,) = compat.optimization_barrier((Gc0,))
+    (Gc0,) = jax.lax.optimization_barrier((Gc0,))
     kept_s = jnp.sum(COL_SCORE_MODES[score_mode](Gc0), axis=0)  # [r]
     Gc = Gc0 * scales[None, :].astype(jnp.float32)
     Wc = jnp.take(W, idx, axis=0).astype(jnp.float32)

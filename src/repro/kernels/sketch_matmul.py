@@ -9,8 +9,10 @@ HBM — the compacted operands are never materialised. The MXU then runs a dense
 *shape* sparsity (DESIGN.md §3).
 
 VMEM budget per grid step (defaults, bf16): G tile 256×128 (64 KiB) + W tile
-128×256 (64 KiB) + fp32 acc 256×256 (256 KiB) ≈ 0.4 MiB — far below the
-~16 MiB/core budget, leaving room for double buffering.
+128×256 (64 KiB) + fp32 acc 256×256 (256 KiB) ≈ 0.4 MiB, double buffering
+included well below the 16 MiB scoped-VMEM limit the v5e compiler enforces
+by default. The fused kernels keep larger accumulators resident; see
+``fused_vmem_bytes``.
 """
 from __future__ import annotations
 
@@ -300,17 +302,27 @@ def block_gather_matmul_fused(G, block_idx, scales, W, X, *, block: int = 128,
 
 
 def fused_vmem_bytes(N: int, d: int, rb: int, block: int, itemsize: int,
-                     tile_n: int = 256, tile_d: int = 256) -> int:
-    """VMEM residency estimate for ``block_gather_matmul_fused`` (bytes).
-
-    f32 accumulators + output buffers + double-buffered input tiles."""
+                     tile_n: int = 256, tile_d: int = 256,
+                     with_scores: bool = False) -> int:
+    """Scoped-VMEM bytes the TPU compiler allocates for
+    ``block_gather_matmul_fused``, counted the way it counts them: the f32
+    accumulators, the double-buffered input tiles, the dX row-panel output
+    double-buffered (its block moves with the row tile), and the compact
+    dW / db (/ scores) outputs once (their block never moves). On a v5e
+    this reproduces the compiler's "Scoped allocation" figure for kernels
+    whose outputs leave through HBM (tests/test_tpu_compile.py). XLA may
+    instead place small outputs in VMEM proper, and then needs less; the
+    dispatcher cannot see that whole-program choice, so it budgets for the
+    HBM case."""
     tn = min(tile_n, max(8, N))
     td = min(tile_d, d)
     dp = -(-d // td) * td
-    acc = 4 * (tn * dp + rb * block * dp + rb * block)
-    outs = itemsize * (tn * dp + rb * block * dp) + 4 * rb * block
+    rbp = -(-rb // 8) * 8  # an f32 [rb, block] tile pads rows to 8 sublanes
+    small = 4 * rbp * block * (2 if with_scores else 1)  # db (+ scores)
+    acc = 4 * (tn * dp + rb * block * dp) + small
     tiles = 2 * itemsize * (tn * block + block * td + tn * td)
-    return acc + outs + tiles
+    outs = itemsize * (2 * tn * dp + rb * block * dp) + small
+    return acc + tiles + outs
 
 
 # ---------------------------------------------------------------------------
@@ -350,10 +362,13 @@ def _stream_kernel(gate_ref, slot_ref, g_ref, w_ref, x_ref,
     # gated contributions: dropped blocks skip both MXU products entirely,
     # so the accumulation sequence over kept blocks (ascending block id =
     # ascending slot) is exactly the fused kernel's — bit-identical outputs
-    # for the same keep decisions.
+    # for the same keep decisions. One scaled tile feeds the dots AND the db
+    # reduction, as in the fused kernel: recomputing the product inside the
+    # reduction lets the compiler fuse it there and round differently.
+    g = graw * sc
+
     @pl.when(sc > 0)
     def _():
-        g = graw * sc
         acc_dx[:, jsl] += jax.lax.dot(g, w_ref[...].astype(jnp.float32),
                                       preferred_element_type=jnp.float32)
         acc_dw[slot, :, jsl] += jax.lax.dot_general(
@@ -362,7 +377,7 @@ def _stream_kernel(gate_ref, slot_ref, g_ref, w_ref, x_ref,
 
     @pl.when(jnp.logical_and(sc > 0, j == 0))
     def _():
-        acc_db[slot, :] += jnp.sum(graw * sc, axis=0)
+        acc_db[slot, :] += jnp.sum(g, axis=0)
 
     @pl.when(k == n_k - 1)
     def _():
@@ -464,9 +479,10 @@ def block_stream_matmul_fused(G, gates, slot_map, W, X, *, rb: int,
 def stream_vmem_bytes(N: int, d: int, rb: int, nb: int, block: int,
                       itemsize: int, tile_n: int = 256,
                       tile_d: int = 256) -> int:
-    """VMEM residency estimate for ``block_stream_matmul_fused`` (bytes):
-    the fused kernel's accumulators plus the [nb, block] score accumulator
-    and its output buffer."""
+    """Scoped-VMEM bytes for ``block_stream_matmul_fused``: the fused
+    kernel's (see :func:`fused_vmem_bytes`) plus the [nb, block] f32 score
+    accumulator and its output."""
+    nbp = -(-nb // 8) * 8
     return (fused_vmem_bytes(N, d, rb, block, itemsize,
                              tile_n=tile_n, tile_d=tile_d)
-            + 8 * nb * block)
+            + 2 * 4 * nbp * block)

@@ -214,6 +214,15 @@ def make_train_step(cfg: ArchConfig, opt: Optimizer, policy: Optional[SketchPoli
             new_opt = gate_update(ok, new_opt, state.opt_state)
             probe_metrics = dict(probe_metrics, sentinel_trip=tripped)
         new_state = TrainState(params=new_params, opt_state=new_opt, step=state.step + 1)
+        if ex.mesh is not None:
+            from repro.train import elastic
+
+            # the state leaves the step in the layout it comes in with (the
+            # mesh's state shardings): left free, XLA shards some replicated
+            # leaves (the norm gains) on the way out, and the next call then
+            # compiles the whole step a second time for the new layout
+            new_state = jax.lax.with_sharding_constraint(
+                new_state, elastic.state_shardings(new_state, ex.mesh))
         metrics = dict(metrics, loss=loss, grad_norm=gn, **probe_metrics)
         return new_state, metrics
 

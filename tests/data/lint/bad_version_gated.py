@@ -8,4 +8,4 @@ def build(devices):
     axis_kind = jax.sharding.AxisType
     mapped = sm
     m2 = jax.sharding.Mesh(devices, ("data",), axis_types=(axis_kind,))
-    return mesh, mapped, m2, jax.lax.optimization_barrier
+    return mesh, mapped, m2

@@ -3,6 +3,7 @@ import, and the backward-fusion bench must run end-to-end in-process (the
 conftest-forced 8 fake devices double as its mesh) and uphold the PR's
 structural claim — the fused backward reads G at most twice."""
 import importlib
+import os
 
 import jax
 import pytest
@@ -232,3 +233,23 @@ def test_g_reader_counter_parses_hlo():
     f = jax.jit(lambda g: (jnp.sum(jnp.abs(g)), g @ g.T))
     txt = f.lower(jax.ShapeDtypeStruct((32, 48), jnp.float32)).compile().as_text()
     assert g_reader_passes(txt, 32, 48) >= 1
+
+
+def test_run_parent_never_imports_jax():
+    """benchmarks/run.py spawns one child per job, and an accelerator belongs
+    to the first process that touches JAX: the parent must import neither
+    JAX nor anything that does (repro, the bench modules)."""
+    import ast
+
+    path = os.path.join(os.path.dirname(__file__), "..", "benchmarks", "run.py")
+    with open(path) as f:
+        tree = ast.parse(f.read())
+    names = []
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            names.append(node.module or "")
+    bad = [n for n in names
+           if n.split(".")[0] in ("jax", "jaxlib", "repro", "benchmarks")]
+    assert not bad, bad

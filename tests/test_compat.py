@@ -133,3 +133,87 @@ def test_compat_tree_and_key_helpers():
     k = compat.prng_key(0)
     assert jax.random.bits(jax.random.fold_in(k, 1), (2,)).shape == (2,)
     assert compat.key_dtype() == k.dtype
+
+
+def test_compilation_cache_placement(monkeypatch, tmp_path):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing else is set in code;
+    without it the cache goes to one fixed, gitignored directory inside
+    the checkout."""
+    from repro import compat
+
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert compat.enable_compilation_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == before
+
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+        assert compat.enable_compilation_cache() == compat.CACHE_DIR
+        assert jax.config.jax_compilation_cache_dir == compat.CACHE_DIR
+        assert os.path.dirname(compat.CACHE_DIR) == root
+        with open(os.path.join(root, ".gitignore")) as f:
+            ignored = f.read().split()
+        assert os.path.basename(compat.CACHE_DIR) + "/" in ignored
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+# Trains a few donating steps with the persistent cache on (every executable
+# cached, however quick its compile) and prints the losses, a digest of the
+# final state's bytes, and how many executables were read back from the cache.
+_CACHED_TRAIN = r"""
+import hashlib, json
+import jax, numpy as np
+from repro import compat
+compat.enable_compilation_cache()
+hits = []
+jax.monitoring.register_event_listener(
+    lambda name, **kw: hits.append(name)
+    if name == "/jax/compilation_cache/cache_hits" else None)
+from repro.api import Runtime, SketchConfig, SketchPolicy
+from repro.configs import gemma3_1b
+from repro.data.synthetic import LMStream
+from repro.optim import adamw
+from repro.train.trainer import TrainerConfig
+cfg = gemma3_1b.SMOKE
+rt = Runtime(policy=SketchPolicy(base=SketchConfig(method="l1", budget=0.5)))
+state, hist = rt.train(cfg, adamw(1e-3),
+                       LMStream(vocab=cfg.vocab, seed=0).batches(2, 16),
+                       TrainerConfig(steps=6, log_every=1, seed=0))
+digest = hashlib.sha256()
+for leaf in jax.tree.leaves(jax.device_get(state)):
+    digest.update(np.ascontiguousarray(leaf).tobytes())
+print(json.dumps({"losses": [h["loss"] for h in hist],
+                  "state": digest.hexdigest(), "hits": len(hits)}))
+"""
+
+
+def test_reloaded_train_step_keeps_donation(tmp_path):
+    """A donating train step read back from the persistent compilation cache
+    trains bit-identically to the freshly compiled one. (Executables reloaded
+    from the cache once lost their input-output aliasing, so the donated
+    state chain read freed buffers with no error.)"""
+    import json
+    import subprocess
+    import sys
+
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+    env = dict(os.environ, JAX_PLATFORMS="cpu", PYTHONPATH=os.path.join(root, "src"),
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0")
+
+    def run():
+        out = subprocess.run([sys.executable, "-c", _CACHED_TRAIN], env=env,
+                             cwd=str(tmp_path), capture_output=True, text=True,
+                             timeout=600)
+        assert out.returncode == 0, out.stderr[-4000:]
+        return json.loads(out.stdout.strip().splitlines()[-1])
+
+    cold = run()
+    assert os.listdir(tmp_path / "cache")
+    warm = run()
+    assert cold["hits"] == 0 and warm["hits"] > 0
+    assert warm["losses"] == cold["losses"]
+    assert warm["state"] == cold["state"]

@@ -45,3 +45,19 @@ def test_prefetch_preserves_order_and_count():
     items = [{"i": np.asarray([k])} for k in range(7)]
     out = list(prefetch(iter(items), size=3))
     assert [int(o["i"][0]) for o in out] == list(range(7))
+
+
+def test_lm_stream_draws_match_rng_choice():
+    """The precomputed-CDF draw is the same stream rng.choice(vocab, p=...)
+    gives: identical tokens, batch after batch."""
+    s = LMStream(vocab=300, seed=4)
+    got = s.batches(3, 20)
+    for step in range(2):
+        rng = np.random.default_rng((4, step))
+        toks = np.empty((3, 21), np.int32)
+        toks[:, 0] = rng.choice(300, size=3, p=s._p)
+        for t in range(20):
+            follow = rng.random(3) < 0.8
+            rand = rng.choice(300, size=3, p=s._p)
+            toks[:, t + 1] = np.where(follow, s._succ[toks[:, t]], rand)
+        np.testing.assert_array_equal(next(got)["tokens"], toks[:, :-1])

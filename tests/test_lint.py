@@ -23,7 +23,7 @@ def _fixture(name):
 
 # (bad fixture, rule id, expected finding lines)
 _BAD = [
-    ("bad_version_gated.py", "jax-version-gated", {2, 7, 8, 9, 10, 11}),
+    ("bad_version_gated.py", "jax-version-gated", {2, 7, 8, 9, 10}),
     ("bad_custom_vjp.py", "custom-vjp-outside-site", {2, 7, 8}),
     ("bad_ctx.py", "ctx-outside-api-nn", {7, 8}),
     ("bad_prng_reuse.py", "prng-key-reuse", {8}),

@@ -89,9 +89,13 @@ def test_multi_step_decode_matches_full_forward():
 
 
 def test_segment_masked_prefill_is_byte_identical_per_prompt():
-    """Right-padded rows with segment ids produce EXACTLY the single-prompt
-    logits: -1e30 masking makes pad contributions exp to exact 0.0, so the
-    engines' bucketed prefill cannot perturb greedy decoding."""
+    """Right-padded rows with segment ids produce EXACTLY the logits of the
+    prompt alone: -1e30 masking makes pad contributions exp to exact 0.0, so
+    the engines' bucketed prefill cannot perturb greedy decoding. "Alone" is
+    the same prompt in the same [2, S] call with every other position
+    (padding and the other row) replaced by unrelated tokens: same shapes,
+    so any difference can only leak through the mask, never from the
+    backend blocking a matmul differently for another row count."""
     params = _params()
     rng = np.random.default_rng(7)
     p1 = rng.integers(1, CFG.vocab, size=11).astype(np.int32)
@@ -104,9 +108,19 @@ def test_segment_masked_prefill_is_byte_identical_per_prompt():
     lg, _ = lm.prefill(params, {"tokens": jnp.asarray(toks),
                                 "segments": jnp.asarray(segs)}, Ctx(), CFG, 32)
     for row, p in ((0, p1), (1, p2)):
-        solo, _ = lm.forward(params, {"tokens": jnp.asarray(p)[None]}, Ctx(), CFG)
+        alone = rng.integers(1, CFG.vocab, size=(2, S)).astype(np.int32)
+        alone[row, :len(p)] = p
+        alone_segs = np.ones((2, S), np.int32)
+        alone_segs[row] = segs[row]
+        solo, _ = lm.prefill(params, {"tokens": jnp.asarray(alone),
+                                      "segments": jnp.asarray(alone_segs)},
+                             Ctx(), CFG, 32)
         np.testing.assert_array_equal(np.asarray(lg[row, :len(p)]),
-                                      np.asarray(solo[0]))
+                                      np.asarray(solo[row, :len(p)]))
+        # and the same logits as the unpadded prompt, to rounding
+        ref, _ = lm.forward(params, {"tokens": jnp.asarray(p)[None]}, Ctx(), CFG)
+        np.testing.assert_allclose(np.asarray(lg[row, :len(p)]),
+                                   np.asarray(ref[0]), rtol=1e-5, atol=1e-5)
 
 
 def test_decode_step_vector_positions():
