@@ -69,11 +69,13 @@ def _ledger_key(runtime, cfg, budget, donate) -> str:
             f"/rt={hash(runtime) & 0xffffffff:08x}")
 
 
-def _with_ledger(jfn, ob, lkey: str, want_memory: bool):
+def _with_ledger(jfn, ob, lkey: str, want_memory: bool, op_table: bool = False):
     """Wrap a jitted step so its first call runs AOT lower+compile, timing
     the trace and compile phases separately and recording
     ``memory_analysis()`` into the shared ledgers; later calls dispatch to
-    the compiled executable directly.
+    the compiled executable directly. With ``op_table`` the compiled HLO's
+    op→layer table (``repro.obs.scopes``) is recorded on ``ob`` once per
+    compile.
 
     Falls back to the plain jitted callable — permanently — if AOT is
     unavailable or a later call arrives with different arg shapes (the
@@ -117,6 +119,10 @@ def _with_ledger(jfn, ob, lkey: str, want_memory: bool):
                 mem = None
         _ledger_compile(ob, lkey, trace_s=t1 - t0, compile_s=t2 - t1,
                         memory=mem)
+        if op_table:
+            from repro.obs import scopes
+
+            ob.record_op_layers(*scopes.op_layer_table(compiled.as_text()))
         if ob is not None and ob.tracer.enabled:
             parent = ob.tracer.current_id()
             ob.tracer.add_span("jit_trace", t0, t1, parent=parent, key=lkey)
@@ -220,8 +226,10 @@ class Runtime:
         ledger_on = jitted and (ob.compile_ledger is not None
                                 or ob.memory_ledger is not None)
         global_on = jitted and ledgers.global_active()
+        # tracing on: the step is wrapped too, to record its op->layer table
+        trace_on = jitted and ob.tracer.enabled
         lkey = (_ledger_key(self, cfg, budget, donate)
-                if (ledger_on or global_on) else None)
+                if (ledger_on or global_on or trace_on) else None)
         key = (self, cfg, opt, budget, donate, jitted)
         fn = _cache_get(key)
         if fn is not None:
@@ -236,9 +244,15 @@ class Runtime:
                              execution=self.execution)
         if jitted:
             fn = jax.jit(fn, donate_argnums=(0,) if donate else ())
+            if trace_on:
+                # the persistent compile cache leaves metadata out of its key
+                # by default: a traced process could load an executable built
+                # from code without the device scopes, and its table would
+                # come out empty
+                jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
             if lkey is not None:
-                fn = _with_ledger(fn, ob if ledger_on else None, lkey,
-                                  ob.memory_ledger is not None)
+                fn = _with_ledger(fn, ob if (ledger_on or trace_on) else None, lkey,
+                                  ob.memory_ledger is not None, op_table=trace_on)
         _cache_put(key, fn)
         return fn
 

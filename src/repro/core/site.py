@@ -53,6 +53,7 @@ from repro.core.compact_grad import (TP_OUT_ROLES, TP_ROW_ROLES, CompactGrad,
                                      _site_role)
 from repro.core.sketching import (SketchConfig, effective_cfg,
                                   static_block_rank, static_rank)
+from repro.obs import scopes
 
 __all__ = ["ExecutionPlan", "SiteSpec", "resolve_site", "resolve_tree_site",
            "sketched_site", "local_spec", "tp_estimator"]
@@ -318,6 +319,13 @@ def _fwd(spec, x, w, b, key, slot, pslot, sslot):
 
 
 def _bwd(spec, res, g):
+    # every site's sketched VJP, local and TP plans alike, on the device as
+    # sketch/vjp (score and plan open their own sub-scopes inside)
+    with compat.named_scope(scopes.SKETCH), compat.named_scope(scopes.VJP):
+        return _bwd_plan(spec, res, g)
+
+
+def _bwd_plan(spec, res, g):
     x, w, key, has_b, slot, want_probe, sslot = res
     kind = spec.plan.kind
     if kind == "local":

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 
 from repro.core import solver
 from repro.core.scores import SCORE_METHODS, column_scores
+from repro.obs import scopes
 
 __all__ = [
     "SketchConfig",
@@ -148,6 +149,7 @@ class ColumnPlan:
     probs: jax.Array  # [n] f32 marginals (diagnostics / tests)
 
 
+@scopes.scoped(scopes.SCORE)
 def _proxy_scores(cfg: SketchConfig, G2d: jax.Array, W: Optional[jax.Array]) -> jax.Array:
     """Column proxy scores, routed through the Pallas reduction kernel for the
     ℓ1/ℓ2 families on the pallas backend (one streaming HBM pass over G with
@@ -179,6 +181,7 @@ def _column_probs(cfg: SketchConfig, G2d: jax.Array, W: Optional[jax.Array], r: 
     return solver.optimal_probabilities(w, r)
 
 
+@scopes.scoped(scopes.PLAN)
 def column_plan(
     cfg: SketchConfig,
     G2d: jax.Array,
@@ -262,6 +265,7 @@ def _weights_from_scores(scores: jax.Array) -> jax.Array:
     return jnp.where(jnp.sum(w) > 0, w, jnp.ones_like(w))
 
 
+@scopes.scoped(scopes.PLAN)
 def column_plan_from_scores(cfg: SketchConfig, scores: jax.Array,
                             key: jax.Array, *,
                             want_compact: bool = True) -> ColumnPlan:

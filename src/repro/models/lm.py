@@ -15,6 +15,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro import compat
 from repro.configs.base import ArchConfig
 from repro.core import linear
 from repro.nn.attention import AttnCfg, attention, attn_init, init_kv_cache
@@ -24,6 +25,7 @@ from repro.nn.moe import MoECfg, moe_ffn, moe_init
 from repro.nn.ssm import (MambaCfg, RWKVCfg, mamba_block, mamba_decode, mamba_init,
                           mamba_state_init, rwkv_channel_mix, rwkv_init,
                           rwkv_state_init, rwkv_time_mix)
+from repro.obs import scopes
 
 __all__ = ["LayerKind", "plan_segments", "init_params", "forward", "decode_step",
            "init_cache", "lm_loss", "num_params", "active_params_per_token"]
@@ -284,6 +286,7 @@ def _layer_uid(seg_base: int, rep, period_len: int, sub_i: int):
     return seg_base + rep * period_len + sub_i
 
 
+@scopes.scoped(scopes.STACK)
 def _run_segments(seg_params, segments, x, ctx: Ctx, cfg: ArchConfig, step_key,
                   positions, shared=None, memory=None, caches=None, pos=None,
                   seg_base: int = 0, segs=None):
@@ -389,6 +392,7 @@ def _run_segments(seg_params, segments, x, ctx: Ctx, cfg: ArchConfig, step_key,
 # ---------------------------------------------------------------------------
 
 
+@scopes.scoped(scopes.EMBED)
 def _embed(params, tokens_or_embeds, cfg: ArchConfig):
     if jnp.issubdtype(tokens_or_embeds.dtype, jnp.integer):
         x = jnp.take(params["embed"], tokens_or_embeds, axis=0)
@@ -454,8 +458,9 @@ def forward(params, batch, ctx: Ctx, cfg: ArchConfig, step_key=None):
     x, aux, _ = _run_segments(params["segments"], segs, x, ctx, cfg, step_key,
                               positions, shared=params.get("shared"), memory=memory,
                               segs=batch.get("segments"))
-    x = rmsnorm(params["final_norm"], x)
-    logits = _head(params, x, ctx, cfg)
+    with compat.named_scope(scopes.HEAD):
+        x = rmsnorm(params["final_norm"], x)
+        logits = _head(params, x, ctx, cfg)
     return logits, aux
 
 
@@ -544,16 +549,17 @@ def lm_loss(params, batch, ctx: Ctx, cfg: ArchConfig, step_key=None):
         return loss, {"loss": loss, "acc": acc, "nll": loss}
     logits, aux = forward(params, batch, ctx, cfg, step_key)
     labels = batch["labels"]
-    lg32 = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(lg32, axis=-1)
-    V = lg32.shape[-1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, lg32.shape, len(lg32.shape) - 1)
-    true_logit = jnp.sum(jnp.where(iota == labels[..., None], lg32, 0.0), axis=-1)
-    nll = lse - true_logit
-    mask = batch.get("mask")
-    if mask is None:
-        mask = jnp.ones_like(nll)
-    loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with compat.named_scope(scopes.HEAD):
+        lg32 = logits.astype(jnp.float32)
+        lse = jax.scipy.special.logsumexp(lg32, axis=-1)
+        V = lg32.shape[-1]
+        iota = jax.lax.broadcasted_iota(jnp.int32, lg32.shape, len(lg32.shape) - 1)
+        true_logit = jnp.sum(jnp.where(iota == labels[..., None], lg32, 0.0), axis=-1)
+        nll = lse - true_logit
+        mask = batch.get("mask")
+        if mask is None:
+            mask = jnp.ones_like(nll)
+        loss = jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
     total = loss + aux
     return total, {"loss": loss, "aux": aux, "nll": loss}
 

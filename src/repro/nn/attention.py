@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.nn.common import Ctx, dense, dense_init
 from repro.nn.rope import apply_mrope, apply_rope
+from repro.obs import scopes
 
 __all__ = ["AttnCfg", "attn_init", "attention", "decode_attention", "init_kv_cache"]
 
@@ -342,6 +343,7 @@ def init_kv_cache(batch: int, max_len: int, cfg: AttnCfg, dtype):
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
+@scopes.scoped(scopes.ATTN)
 def attention(params, x, ctx: Ctx, cfg: AttnCfg, positions, cache=None, pos=None,
               memory=None, role_prefix: str = "attn", segs=None):
     """Full attention sublayer: projections (sketched) + core + out-proj.

@@ -5,6 +5,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.nn.common import ACTIVATIONS, Ctx, dense, dense_init
+from repro.obs import scopes
 
 __all__ = ["mlp_init", "mlp"]
 
@@ -20,6 +21,7 @@ def mlp_init(key, d_model: int, d_ff: int, mlp_type: str, dtype=jnp.float32):
     return p
 
 
+@scopes.scoped(scopes.FFN)
 def mlp(params, x, ctx: Ctx, mlp_type: str, role_prefix: str = "mlp"):
     h = dense(params["in"], x, ctx, f"{role_prefix}_in")
     if mlp_type in _GLU:

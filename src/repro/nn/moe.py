@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from repro import compat
 from repro.nn.common import Ctx, dense_init
 from repro.core import linear
+from repro.obs import scopes
 
 __all__ = ["MoECfg", "moe_init", "moe_ffn"]
 
@@ -127,6 +128,7 @@ def _moe_local(router_w, wi, wg, wo, x2d, ctx: Ctx, cfg: MoECfg, e_offset: int,
     return y, {"me": me, "disp": disp}
 
 
+@scopes.scoped(scopes.FFN)
 def moe_ffn(params, x, ctx: Ctx, cfg: MoECfg):
     """x: [B, S, d] -> (y, aux_loss scalar)."""
     B, S, d = x.shape

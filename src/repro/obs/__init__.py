@@ -16,6 +16,9 @@ across training, serving and recovery:
   ``device.memory_stats()`` where hardware has them);
 * :mod:`repro.obs.flight` — bounded recent-history ring dumped as a crash
   bundle by the resilience Supervisor;
+* :mod:`repro.obs.scopes` — the device scopes that name the train step's
+  layers in the compiled program, and the op→layer table read from it
+  (recorded per step executable when tracing is on: :meth:`Observability.op_layers`);
 * :mod:`repro.obs.clock` — the one sanctioned wall-clock source
   (lint-enforced: ``time.perf_counter``/``time.time`` are forbidden in
   ``src/`` outside this package).
@@ -42,7 +45,7 @@ from repro.obs.ledgers import (CompileLedger, MemoryLedger,
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.tracing import NULL_TRACER, Tracer
 
-__all__ = ["ObsConfig", "Observability", "observability", "NULL_OBS"]
+__all__ = ["ObsConfig", "Observability", "observability", "shared", "NULL_OBS"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -119,6 +122,23 @@ class Observability:
         # subsystems (each serving engine owns its counters but registers
         # here so report()/prometheus() see them)
         self.components: List[Tuple[str, MetricsRegistry]] = []
+        # HLO module name -> {instruction: (layer, scope path)}, one per step
+        # executable built with tracing on (repro.obs.scopes)
+        self._op_layers: Dict[str, Dict[str, Tuple[Optional[str], str]]] = {}
+
+    # -- device scopes --------------------------------------------------------
+
+    def record_op_layers(self, module: str, table: dict) -> None:
+        # executables of one step function (budget buckets) share the HLO
+        # module name: their tables merge, a later build's entry winning
+        self._op_layers.setdefault(module, {}).update(table)
+
+    def op_layers(self) -> Dict[str, Dict[str, Tuple[Optional[str], str]]]:
+        """{HLO module name: {instruction name: (layer, scope path)}} for
+        every step executable built while tracing was on — the join key of
+        a profiler trace's device ops (module, op name) to the program's
+        layers. Empty with tracing off."""
+        return self._op_layers
 
     # -- component registries ----------------------------------------------
 
@@ -163,6 +183,8 @@ class Observability:
             out["memory"] = self.memory_ledger.to_json()
         out["metrics"] = self.metrics_snapshot()
         out["n_spans"] = len(self.tracer.spans())
+        if self._op_layers:
+            out["op_layers"] = self._op_layers
         return out
 
     def export(self) -> List[str]:
@@ -198,6 +220,13 @@ def observability(cfg: Optional[ObsConfig]) -> Observability:
     if ob is None:
         ob = _OBS[cfg] = Observability(cfg)
     return ob
+
+
+def shared() -> List[Observability]:
+    """Every shared :class:`Observability` this process has made — how a
+    reader outside the program (a profiler-trace reduction) finds the op
+    tables and host spans of the run it traced."""
+    return list(_OBS.values())
 
 
 def _reset() -> None:  # test hook

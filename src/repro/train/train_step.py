@@ -31,6 +31,7 @@ from repro.core import SketchPolicy
 from repro.core import compact_grad as cgrad
 from repro.core import plan_state as pstate
 from repro.models import lm
+from repro.obs import scopes
 from repro.optim import Optimizer, global_grad_norm
 
 __all__ = ["TrainState", "make_train_step", "init_state"]
@@ -188,31 +189,35 @@ def make_train_step(cfg: ArchConfig, opt: Optimizer, policy: Optional[SketchPoli
             zeros = compat.tree_map(lambda p: jnp.zeros(p.shape, jnp.float32), state.params)
             (loss, grads), metrics = jax.lax.scan(micro, (jnp.zeros(()), zeros), (mbs, keys))
             metrics = compat.tree_map(lambda m: m[-1], metrics)
-        fresh_scores = {}
-        if carry_on:
-            # plan carry: the sslot cotangents ARE the refreshed scores —
-            # pull them out (zeroing the leaves keeps the gradient tree
-            # congruent for the optimizer and the grad norm; under accum the
-            # scan has averaged the microbatches' scores, still a valid carry)
-            grads, fresh_scores = pstate.collect_plan_state(grads)
-        new_params, new_opt = opt.update(grads, state.opt_state, state.params, state.step)
-        if fresh_scores:
-            # write the refreshed carry over whatever the optimizer did to
-            # the sslot leaves (zero grads ⇒ only decay touched them) —
-            # BEFORE sentinel gating, so a tripped step keeps the old carry
-            new_params = pstate.write_plan_state(new_params, fresh_scores)
-        gn = _global_norm(grads)
-        if rcfg is not None and rcfg.sentinel:
-            from repro.resilience.sentinel import gate_update, trip_flag
+        with compat.named_scope(scopes.OPTIM):
+            fresh_scores = {}
+            if carry_on:
+                # plan carry: the sslot cotangents ARE the refreshed scores —
+                # pull them out (zeroing the leaves keeps the gradient tree
+                # congruent for the optimizer and the grad norm; under accum
+                # the scan has averaged the microbatches' scores, still a
+                # valid carry)
+                grads, fresh_scores = pstate.collect_plan_state(grads)
+            new_params, new_opt = opt.update(grads, state.opt_state, state.params,
+                                             state.step)
+            if fresh_scores:
+                # write the refreshed carry over whatever the optimizer did to
+                # the sslot leaves (zero grads ⇒ only decay touched them) —
+                # BEFORE sentinel gating, so a tripped step keeps the old carry
+                new_params = pstate.write_plan_state(new_params, fresh_scores)
+            gn = _global_norm(grads)
+            if rcfg is not None and rcfg.sentinel:
+                from repro.resilience.sentinel import gate_update, trip_flag
 
-            # one scalar out of quantities the step already materializes;
-            # a tripped step keeps the old params AND opt state (the moment
-            # buffers must not ingest a poisoned gradient) — the step
-            # counter still advances so the schedule/PRNG stay on track
-            ok, tripped = trip_flag(loss, gn, rcfg.max_grad_norm)
-            new_params = gate_update(ok, new_params, state.params)
-            new_opt = gate_update(ok, new_opt, state.opt_state)
-            probe_metrics = dict(probe_metrics, sentinel_trip=tripped)
+                # one scalar out of quantities the step already materializes;
+                # a tripped step keeps the old params AND opt state (the
+                # moment buffers must not ingest a poisoned gradient) — the
+                # step counter still advances so the schedule/PRNG stay on
+                # track
+                ok, tripped = trip_flag(loss, gn, rcfg.max_grad_norm)
+                new_params = gate_update(ok, new_params, state.params)
+                new_opt = gate_update(ok, new_opt, state.opt_state)
+                probe_metrics = dict(probe_metrics, sentinel_trip=tripped)
         new_state = TrainState(params=new_params, opt_state=new_opt, step=state.step + 1)
         if ex.mesh is not None:
             from repro.train import elastic

@@ -222,6 +222,20 @@ def train_loop(runtime: Runtime, cfg: ArchConfig, opt: Optimizer,
 
     sink = tsinks.build_sinks(tel)
 
+    def sync(metrics, scalars_only, wait_only):
+        if wait_only:
+            jax.block_until_ready(metrics["loss"])
+            return None
+        return _host_metrics(metrics, scalars_only=scalars_only)
+
+    def fetch(metrics, step, *, scalars_only=False, wait_only=False):
+        # every device->host fetch (or bare wait) of the loop: a train_fetch
+        # span when traced, no clock read when not
+        if traced:
+            with tracer.span("train_fetch", step=step):
+                return sync(metrics, scalars_only, wait_only)
+        return sync(metrics, scalars_only, wait_only)
+
     def emit(rec: dict):
         if sink is not None:
             sink.write(dict(rec))
@@ -255,7 +269,11 @@ def train_loop(runtime: Runtime, cfg: ArchConfig, opt: Optimizer,
     try:
       with loop_span:
         for step in range(start_step, tcfg.steps):
-            batch = next(data_it)
+            if traced:
+                with tracer.span("train_data", step=step):
+                    batch = next(data_it)
+            else:
+                batch = next(data_it)
             fscale = 1.0
             if injector is not None:
                 fault = injector.take(step)
@@ -307,12 +325,12 @@ def train_loop(runtime: Runtime, cfg: ArchConfig, opt: Optimizer,
             host_m = None  # full fetch (sink/log cadence only)
             host_scalars = None
             if controller or sentinel is not None:
-                jax.block_until_ready(metrics["loss"])
                 # per-step fetch stays scalars-only: the controller consumes
                 # one scalar (probe_snr), the sentinel a handful; per-site
                 # vectors are fetched on sink/log steps below
-                if fetch_each_step or sentinel is not None:
-                    host_scalars = _host_metrics(metrics, scalars_only=True)
+                host_scalars = fetch(
+                    metrics, step, scalars_only=True,
+                    wait_only=not (fetch_each_step or sentinel is not None))
             if controller:
                 controller.step_end(host_scalars if fetch_each_step else None)
             if sentinel is not None:
@@ -329,7 +347,7 @@ def train_loop(runtime: Runtime, cfg: ArchConfig, opt: Optimizer,
                     raise RollbackRequired(step, sentinel.last_cause,
                                            history=history)
             if sink is not None and step % tel.interval == 0:
-                host_m = _host_metrics(metrics)
+                host_m = fetch(metrics, step)
                 sink.write(dict(host_m, step=step, budget=budget))
             if budget_gauge is not None and (
                     step % tcfg.log_every == 0 or step == tcfg.steps - 1):
@@ -337,7 +355,7 @@ def train_loop(runtime: Runtime, cfg: ArchConfig, opt: Optimizer,
                 if ob.flight is not None:
                     ob.flight.snapshot(step)
             if step % tcfg.log_every == 0 or step == tcfg.steps - 1:
-                m = host_m if host_m is not None else _host_metrics(metrics)
+                m = host_m if host_m is not None else fetch(metrics, step)
                 m = dict(m, step=step, budget=budget)
                 history.append(m)
                 if on_metrics:
