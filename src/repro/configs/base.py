@@ -44,7 +44,7 @@ class ArchConfig:
     q_chunk: int = 512
     kv_chunk: int = 1024
     ssm_chunk: int = 256
-    attn_impl: str = "chunked"  # chunked | einsum | pallas
+    attn_impl: str = "pallas"  # pallas (flash kernel on TPU, else chunked) | chunked | einsum
     remat: str = "full"  # full | dots | none
     # frontend stub
     frontend: Optional[str] = None  # vision | audio

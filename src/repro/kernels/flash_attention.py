@@ -1,15 +1,19 @@
-"""Pallas TPU flash attention (forward) with causal / sliding-window masking.
+"""Pallas TPU flash attention for training and prefill: forward and backward.
 
-Grid: (batch*kv_head*group, num_q_tiles, num_kv_tiles), kv innermost. Online
-softmax state (m, l, fp32 acc) lives in VMEM scratch and survives across the
-kv grid dimension. Causal/window tiles that are fully masked are skipped with
-``pl.when`` (no MXU work issued). Q/K/V tiles are (TQ, dh)/(TK, dh) — dh is
-the lane dimension (128/256 aligned for the assigned archs; 64 packs at half
-lane utilisation, documented).
+A thin wrapper over the splash attention kernels that ship with JAX
+(``jax.experimental.pallas.ops.tpu.splash_attention``): a forward that saves
+the logsumexp, and separate dQ and dKV kernels under their ``custom_vjp``.
+Scores and probabilities live in VMEM only. The QKᵀ, dP, dQ, dK and dV dots
+take bf16 operands with float32 accumulation; the forward's PV dot takes
+float32 probabilities and values (the splash kernel upcasts V). The mask is
+block-sparse: fully masked (causal, or outside the sliding window) blocks are
+skipped, with no MXU work and no DMA.
 
-Training backward uses the chunked XLA path (`nn.attention`); this kernel is
-the serving/prefill fast path — matching MaxText's split, where the fwd kernel
-dominates inference cost.
+Layout: each KV head is one MQA problem over its G query heads, so K/V are
+never repeated to H heads; the kernel is vmapped over batch × KV heads. The
+``d_head ** -0.5`` scale is applied to q before the call. S is padded up to
+the block size; causal masks keep real queries off padded keys, and a
+non-causal call with padding masks them with segment ids.
 """
 from __future__ import annotations
 
@@ -17,101 +21,85 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
-__all__ = ["flash_attention"]
+__all__ = ["flash_attention", "block_sizes"]
 
-_NEG = -1e30
-
-
-def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-            scale: float, causal: bool, window, tq: int, tk: int, n_k: int,
-            sq: int, skv: int):
-    qi = pl.program_id(1)
-    kj = pl.program_id(2)
-
-    @pl.when(kj == 0)
-    def _():
-        m_ref[...] = jnp.full_like(m_ref, _NEG)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    q_hi = qi * tq + tq - 1 + (skv - sq)  # causal offset: right-aligned
-    k_lo = kj * tk
-    live = True
-    if causal:
-        live = k_lo <= q_hi
-
-    @pl.when(live)
-    def _():
-        q = q_ref[0].astype(jnp.float32)  # [tq, dh]
-        k = k_ref[0].astype(jnp.float32)  # [tk, dh]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * scale
-        qpos = (skv - sq) + qi * tq + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 0)
-        kpos = kj * tk + jax.lax.broadcasted_iota(jnp.int32, (tq, tk), 1)
-        mask = kpos < skv
-        if causal:
-            mask &= qpos >= kpos
-            if window is not None:
-                mask &= (qpos - kpos) < window
-        s = jnp.where(mask, s, _NEG)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
-            p, v_ref[0].astype(jnp.float32), preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
-
-    @pl.when(kj == n_k - 1)
-    def _():
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(o_ref.dtype)
+_LANES = 128
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "tile_q", "tile_k",
-                                             "interpret"))
+def _pow2_at_least(n: int) -> int:
+    return 1 << max(0, (n - 1).bit_length())
+
+
+def block_sizes(seq: int, d_head: int) -> splash.BlockSizes:
+    """Tile sizes from the (padded) sequence length and the head size.
+
+    Larger tiles feed the MXU longer runs and cut per-step overhead; the
+    VMEM a tile needs grows with ``d_head``, so tiles shrink as it grows:
+    1024 rows at d_head 128 (the fastest of a sweep on a v5e at S 4096,
+    PERF.md §5), 512 at 256. Every size is a power of two of at least 128
+    lanes and divides the padded length (see :func:`_padded`)."""
+    unit = _pow2_at_least(max(seq, _LANES))
+    blk = min(max(_LANES, 1024 * _LANES // max(d_head, _LANES)), unit)
+    sub = min(blk, 512)
+    return splash.BlockSizes(
+        block_q=blk, block_kv=blk, block_kv_compute=sub,
+        block_q_dkv=blk, block_kv_dkv=blk, block_kv_dkv_compute=sub,
+        block_q_dq=blk, block_kv_dq=blk)
+
+
+def _padded(seq: int, bs: splash.BlockSizes) -> int:
+    return -(-seq // bs.block_q) * bs.block_q
+
+
+@functools.lru_cache(maxsize=64)
+def _kernel(seq: int, groups: int, causal: bool, window, bs: splash.BlockSizes,
+            interpret: bool):
+    """The splash MQA kernel for one KV head and its ``groups`` query heads,
+    built once per shape, mask and tiling."""
+    shape = (seq, seq)
+    if not causal:
+        mask = splash.FullMask(shape)
+    elif window is None:
+        mask = splash.CausalMask(shape)
+    else:
+        mask = splash.LocalMask(shape, window_size=(window - 1, 0), offset=0)
+    # the mask tables are constants of every program that calls the kernel:
+    # make them concrete even when the first call comes inside a trace
+    with jax.ensure_compile_time_eval():
+        return splash.make_splash_mqa_single_device(
+            splash.MultiHeadMask([mask] * groups), block_sizes=bs,
+            interpret=interpret)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
-                    tile_q: int = 256, tile_k: int = 256, interpret: bool = False):
-    """q: [B, Sq, H, dh]; k/v: [B, Skv, Kv, dh] (GQA) -> [B, Sq, H, dh]."""
-    B, Sq, H, dh = q.shape
-    Skv, Kv = k.shape[1], k.shape[2]
+                    interpret: bool = False):
+    """Self-attention. q: [B, S, H, dh]; k/v: [B, S, Kv, dh] (GQA, MQA or
+    MHA) -> [B, S, H, dh] in q's dtype. ``window`` (causal only) keeps the
+    keys of the last ``window`` positions, the query's own included."""
+    B, S, H, dh = q.shape
+    Kv = k.shape[2]
+    if k.shape[1] != S:
+        raise ValueError(f"self-attention only: {S} queries against {k.shape[1]} keys")
     G = H // Kv
-    tq = min(tile_q, Sq)
-    tk = min(tile_k, Skv)
-    Sqp = -(-Sq // tq) * tq
-    Skp = -(-Skv // tk) * tk
-    # layout: fold heads into the leading grid dim -> [B*Kv*G, S, dh]
-    qh = jnp.moveaxis(q.reshape(B, Sq, Kv, G, dh), 1, 3).reshape(B * Kv * G, Sq, dh)
-    kh = jnp.moveaxis(k, 1, 2).reshape(B * Kv, Skv, dh)
-    kh = jnp.repeat(kh, G, axis=0)
-    vh = jnp.moveaxis(v, 1, 2).reshape(B * Kv, Skv, dh)
-    vh = jnp.repeat(vh, G, axis=0)
-    if Sqp != Sq:
-        qh = jnp.pad(qh, ((0, 0), (0, Sqp - Sq), (0, 0)))
-    if Skp != Skv:
-        kh = jnp.pad(kh, ((0, 0), (0, Skp - Skv), (0, 0)))
-        vh = jnp.pad(vh, ((0, 0), (0, Skp - Skv), (0, 0)))
-
-    grid = (B * H, Sqp // tq, Skp // tk)
-    out = pl.pallas_call(
-        functools.partial(_kernel, scale=dh ** -0.5, causal=causal, window=window,
-                          tq=tq, tk=tk, n_k=Skp // tk, sq=Sq, skv=Skv),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, tq, dh), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, tk, dh), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, tk, dh), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, tq, dh), lambda b, i, j: (b, i, 0)),
-        scratch_shapes=[pltpu.VMEM((tq, 1), jnp.float32),
-                        pltpu.VMEM((tq, 1), jnp.float32),
-                        pltpu.VMEM((tq, dh), jnp.float32)],
-        out_shape=jax.ShapeDtypeStruct((B * H, Sqp, dh), q.dtype),
-        interpret=interpret,
-        name="flash_attention_fwd",
-    )(qh, kh, vh)
-    out = out[:, :Sq].reshape(B, Kv, G, Sq, dh)
-    return jnp.moveaxis(out, 3, 1).reshape(B, Sq, H, dh)
+    bs = block_sizes(S, dh)
+    Sp = _padded(S, bs)
+    q = (q.astype(jnp.float32) * dh ** -0.5).astype(q.dtype)
+    # [B, S, Kv, G, dh] -> [B*Kv, G, S, dh]; k/v [B, S, Kv, dh] -> [B*Kv, S, dh]
+    qh = jnp.moveaxis(q.reshape(B, S, Kv, G, dh), 1, 3).reshape(B * Kv, G, S, dh)
+    kh = jnp.moveaxis(k, 1, 2).reshape(B * Kv, S, dh)
+    vh = jnp.moveaxis(v, 1, 2).reshape(B * Kv, S, dh)
+    segs = None
+    if Sp != S:
+        pad = Sp - S
+        qh = jnp.pad(qh, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        kh = jnp.pad(kh, ((0, 0), (0, pad), (0, 0)))
+        vh = jnp.pad(vh, ((0, 0), (0, pad), (0, 0)))
+        if not causal:
+            ids = (jnp.arange(Sp) >= S).astype(jnp.int32)
+            segs = splash.SegmentIds(q=ids, kv=ids)
+    kernel = _kernel(Sp, G, causal, window if causal else None, bs, interpret)
+    o = jax.vmap(kernel, in_axes=(0, 0, 0, None))(qh, kh, vh, segs)
+    o = o[:, :, :S].reshape(B, Kv, G, S, dh)
+    return jnp.moveaxis(o, 3, 1).reshape(B, S, H, dh)

@@ -31,7 +31,7 @@ from repro.kernels.sketch_matmul import (block_gather_matmul as _bgm_pallas,
 __all__ = ["on_tpu", "block_gather_matmul", "block_gather_matmul_dw",
            "block_gather_matmul_fused", "block_stream_matmul_fused",
            "gather_cols_matmul", "gather_cols_matmul_dw", "col_l1_scores",
-           "flash_attention", "fused_vmem_limit", "configure"]
+           "use_flash", "flash_attention", "fused_vmem_limit", "configure"]
 
 # The TPU compiler's default scoped-VMEM limit on a v5e: it refuses a kernel
 # whose scoped allocation exceeds it ("limit 16.00M"), and
@@ -202,7 +202,21 @@ def col_l1_scores(G, *, mode: str = "l1"):
     return kref.col_scores_ref(G, mode=mode)
 
 
+def use_flash(q, k, *, cost_mode: bool, segmented: bool, sharded: bool) -> bool:
+    """Whether attention of q [B, Sq, H, dh] over k [B, Skv, Kv, dh] takes
+    the Pallas flash kernel: on a TPU, outside cost mode, without segment
+    ids, outside a device mesh (XLA cannot partition a Mosaic kernel), for
+    self-attention (Sq == Skv) with d_head a multiple of the 128 lanes.
+    Every other call takes the chunked XLA path. Records the choice at trace
+    time as ``kernels.flash.dispatch`` or ``kernels.flash.fallback``."""
+    take = (on_tpu() and not cost_mode and not segmented and not sharded
+            and q.shape[1] == k.shape[1] and q.shape[-1] % 128 == 0)
+    if _METRICS is not None:
+        _METRICS.counter("kernels.flash." + ("dispatch" if take else "fallback")).inc()
+    return take
+
+
 def flash_attention(q, k, v, *, causal: bool = True, window=None):
-    if _use_pallas():
-        return _flash_pallas(q, k, v, causal=causal, window=window, interpret=not on_tpu())
-    return kref.flash_attention_ref(q, k, v, causal=causal, window=window)
+    """The Pallas flash kernel (forward and backward) for self-attention;
+    callers check :func:`use_flash` first."""
+    return _flash_pallas(q, k, v, causal=causal, window=window, interpret=not on_tpu())

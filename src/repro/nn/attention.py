@@ -1,12 +1,21 @@
 """Attention: GQA/MQA with RoPE / M-RoPE, causal, bidirectional, sliding-window.
 
-Three execution paths:
-  * chunked  — memory-bounded double-chunked online-softmax attention (the XLA
-               fallback used for dry-runs and CPU; never materialises S×S).
-               Sliding-window layers statically slice only ``window + Cq`` keys
-               per query chunk, so locality is a *shape-level* FLOP saving.
+``AttnCfg.impl`` picks how the attention core (scores, softmax, PV) runs:
+  * pallas   — the default. On a TPU, self-attention in training and prefill
+               runs the Pallas flash kernel with its own backward
+               (``kernels/flash_attention.py``): dead causal or window blocks
+               skipped, bf16 MXU operands (but in the forward's PV), no
+               score tile in HBM, K/V never repeated to H heads. ``kernels.ops.use_flash`` decides from
+               what the call shows (TPU, not cost mode, no segment ids, no
+               device mesh, Sq == Skv, d_head a multiple of 128); every other
+               call — CPU, dry-run cost artifacts, segment-masked prefill,
+               sharded steps, cross-attention — falls through to the chunked
+               path.
+  * chunked  — memory-bounded double-chunked online-softmax attention in XLA
+               (never materialises S×S). Sliding-window layers statically
+               slice only ``window + Cq`` keys per query chunk, so locality
+               is a *shape-level* FLOP saving.
   * einsum   — naive reference (tests, tiny shapes).
-  * pallas   — Pallas flash kernel (TPU target; interpret-mode on CPU tests).
 Decode (one query against a cache) uses a dedicated masked-einsum path.
 """
 from __future__ import annotations
@@ -35,7 +44,7 @@ class AttnCfg:
     theta: float = 10000.0
     q_chunk: int = 512
     kv_chunk: int = 512
-    impl: str = "chunked"  # chunked | einsum | pallas
+    impl: str = "pallas"  # pallas | chunked | einsum
     cross: bool = False  # cross-attention (no rope on kv side, bidir)
 
     @property
@@ -172,7 +181,7 @@ def _q_chunk_window(qi, k_pad, v_pad, scale, window, i, q_chunk, qpos, cost_mode
 
 
 def multi_head_attention(q, k, v, cfg: AttnCfg, *, cost_mode: bool = False,
-                         q_offset=0, constrain=None, segs=None):
+                         q_offset=0, constrain=None, segs=None, sharded: bool = False):
     """q:[B,Sq,H,dh] k,v:[B,Skv,Kv,dh] -> [B,Sq,H,dh] (fp32 accum).
 
     GQA k/v are repeated to H heads up front (flat-head layout): the repeat is
@@ -183,18 +192,23 @@ def multi_head_attention(q, k, v, cfg: AttnCfg, *, cost_mode: bool = False,
 
     ``segs`` (int32 [B, Sq], self-attention only) are packed-prefill segment
     ids: tokens attend only within their own segment and id 0 marks padding
-    (docs/serving.md). The pallas flash kernel has no segment support, so a
-    segs-bearing call routes through the chunked XLA path.
+    (docs/serving.md). ``sharded``: the call runs under a device mesh
+    (``Ctx.mesh``). Under ``impl="pallas"`` a call the flash kernel does not
+    take (``kernels.ops.use_flash``: off a TPU, cost mode, ``segs``,
+    ``sharded``, cross-attention, d_head not a multiple of 128) runs the
+    chunked path.
     """
     B, Sq, H, dh = q.shape
     Kv = k.shape[2]
     G = H // Kv
     scale = dh ** -0.5
 
-    if cfg.impl == "pallas" and segs is None:
+    if cfg.impl == "pallas":
         from repro.kernels import ops as kops
-        o = kops.flash_attention(q, k, v, causal=cfg.causal, window=cfg.window)
-        return o.astype(q.dtype)
+        if kops.use_flash(q, k, cost_mode=cost_mode, segmented=segs is not None,
+                          sharded=sharded):
+            o = kops.flash_attention(q, k, v, causal=cfg.causal, window=cfg.window)
+            return o.astype(q.dtype)
 
     if G > 1:
         # Pin the GQA k/v layout on BOTH sides of the head repeat. The repeat
@@ -392,7 +406,8 @@ def attention(params, x, ctx: Ctx, cfg: AttnCfg, positions, cache=None, pos=None
 
     o = multi_head_attention(q, k, v, cfg, cost_mode=ctx.cost_mode,
                              constrain=ctx.constrain_heads,
-                             segs=None if memory is not None else segs)
+                             segs=None if memory is not None else segs,
+                             sharded=ctx.mesh is not None)
     out = dense(params["o"], o.reshape(B, S, -1), ctx, f"{role_prefix}_o")
     if cache is not None:
         # prefill: fill the cache with the (possibly window-truncated) tail.

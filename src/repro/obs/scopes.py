@@ -84,6 +84,8 @@ def layer_of(op_name: str) -> Tuple[Optional[str], str]:
 
 
 _MODULE = re.compile(r"^HloModule\s+([^\s,]+)")
+# a line that opens a module or a computation (``%name (params) -> ... {``)
+_HEADER = re.compile(r"^(?:HloModule\s|ENTRY\s|%?[\w.\-]+\s+\()")
 _INSTR = re.compile(r"^\s+(?:ROOT\s+)?%?([^\s=%]+)\s*=\s")
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _CALLS = re.compile(r"\b(?:calls|to_apply|body|condition|true_computation|"
@@ -91,6 +93,21 @@ _CALLS = re.compile(r"\b(?:calls|to_apply|body|condition|true_computation|"
 _CALL_LISTS = re.compile(r"\b(?:branch_computations|called_computations)=\{([^}]*)\}")
 
 Entry = Tuple[Optional[str], str]
+
+
+def _lines(hlo_text: str):
+    """The text's lines with each instruction whole: a kernel's attributes
+    may hold line breaks (the splash attention kernels' ``kernel_metadata``),
+    which leave the rest of the instruction, its ``op_name`` included, on
+    lines of their own that start at column 0 and open no computation."""
+    out: list = []
+    for line in hlo_text.splitlines():
+        if (out and line and not line[0].isspace() and line.rstrip() != "}"
+                and not _HEADER.match(line)):
+            out[-1] += line
+        else:
+            out.append(line)
+    return out
 
 
 def op_layer_table(hlo_text: str) -> Tuple[str, Dict[str, Entry]]:
@@ -110,7 +127,7 @@ def op_layer_table(hlo_text: str) -> Tuple[str, Dict[str, Entry]]:
     caller: Dict[str, str] = {}        # computation -> first instruction calling it
     fused: Dict[str, list] = {}        # fusion instruction -> its computations
     parsed: Dict[str, Entry] = {}
-    for line in hlo_text.splitlines():
+    for line in _lines(hlo_text):
         if not line or line[0] == "}":
             continue
         if not line[0].isspace():

@@ -1,4 +1,7 @@
-"""Chunked XLA attention vs naive reference: GQA, window, ragged, offsets."""
+"""Attention: chunked XLA attention vs naive reference (GQA, window, ragged,
+offsets), and the dispatch between the flash kernel and the chunked path."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -130,3 +133,72 @@ def test_rope_broadcast_gets_sharding_annotation():
     np.testing.assert_allclose(
         np.asarray(apply_rope(x, pos, 1e4, ctx=ctx)),
         np.asarray(apply_rope(x, pos, 1e4)), rtol=1e-6)
+
+
+def _flash_counters(monkeypatch, tpu: bool):
+    """A fresh metrics registry bound to the kernel dispatch, and the
+    dispatch told it runs on a TPU (or not)."""
+    from repro.kernels import ops
+    from repro.obs.metrics import MetricsRegistry
+
+    reg = MetricsRegistry()
+    monkeypatch.setattr(ops, "_METRICS", reg)
+    monkeypatch.setattr(ops, "on_tpu", lambda: tpu)
+    return lambda name: reg.counter(f"kernels.flash.{name}").value
+
+
+@pytest.mark.parametrize("case", ["cpu", "segs", "cost_mode", "sharded", "cross",
+                                  "d_head_64"])
+def test_flash_dispatch_falls_back_to_chunked(monkeypatch, case):
+    """Calls the flash kernel does not take run the chunked path — exactly
+    what ``impl="chunked"`` computes, never the S x S reference — and count
+    as ``kernels.flash.fallback``."""
+    count = _flash_counters(monkeypatch, tpu=case != "cpu")
+    B, S, H, Kv = 2, 64, 4, 2
+    dh = 64 if case == "d_head_64" else 128
+    Skv = 96 if case == "cross" else S
+    ks = jax.random.split(jax.random.key(11), 3)
+    q = jax.random.normal(ks[0], (B, S, H, dh))
+    k = jax.random.normal(ks[1], (B, Skv, Kv, dh))
+    v = jax.random.normal(ks[2], (B, Skv, Kv, dh))
+    kw = {}
+    if case == "segs":
+        kw["segs"] = jnp.concatenate([jnp.ones((B, 40), jnp.int32),
+                                      2 * jnp.ones((B, S - 40), jnp.int32)], axis=1)
+    if case in ("cost_mode", "sharded"):
+        kw[case] = True
+    cfg = AttnCfg(n_heads=H, n_kv=Kv, d_head=dh, causal=case != "cross",
+                  q_chunk=16, kv_chunk=16)
+    assert cfg.impl == "pallas"
+    got = multi_head_attention(q, k, v, cfg, **kw)
+    want = multi_head_attention(q, k, v, dataclasses.replace(cfg, impl="chunked"), **kw)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    assert (count("dispatch"), count("fallback")) == (0, 1)
+
+
+def test_flash_dispatch_counts_one_call_per_attention_layer(monkeypatch):
+    """On a TPU every self-attention layer traced takes the flash kernel:
+    one ``kernels.flash.dispatch`` per traced call, no fallback. Traced
+    only (``eval_shape``): the kernel itself runs only on a chip."""
+    from repro.configs.base import ArchConfig
+    from repro.models import lm
+    from repro.nn import attention as attn_mod
+    from repro.nn.common import Ctx
+
+    count = _flash_counters(monkeypatch, tpu=True)
+    calls = []
+    inner = attn_mod.multi_head_attention
+
+    def spy(*a, **kw):
+        calls.append(1)
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(attn_mod, "multi_head_attention", spy)
+    cfg = ArchConfig(name="flash-dispatch", family="dense", n_layers=3, d_model=256,
+                     n_heads=4, n_kv=2, d_head=128, d_ff=512, vocab=128)
+    assert cfg.attn_impl == "pallas"
+    params = jax.eval_shape(lambda key: lm.init_params(key, cfg), jax.random.key(0))
+    batch = {"tokens": jnp.zeros((2, 256), jnp.int32), "labels": jnp.zeros((2, 256), jnp.int32)}
+    jax.eval_shape(jax.grad(lambda p: lm.lm_loss(p, batch, Ctx(), cfg)[0]), params)
+    assert calls and count("dispatch") == len(calls)
+    assert count("fallback") == 0

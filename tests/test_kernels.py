@@ -1,4 +1,5 @@
 """Pallas kernels (interpret mode) vs pure-jnp oracles: shape/dtype sweeps."""
+import functools
 import os
 
 import jax
@@ -283,22 +284,83 @@ def test_col_scores(N, n, dt, mode):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-2 if dt == jnp.bfloat16 else 1e-5)
 
 
-@pytest.mark.parametrize("B,Sq,Skv,H,Kv,dh,causal,window,dt", [
-    (2, 128, 128, 4, 2, 64, True, None, jnp.float32),
-    (1, 96, 96, 4, 4, 64, True, 32, jnp.float32),
-    (2, 64, 192, 4, 1, 128, True, None, jnp.float32),
-    (1, 128, 128, 2, 2, 64, False, None, jnp.float32),
-    (1, 128, 128, 4, 2, 64, True, None, jnp.bfloat16),
-    (1, 100, 100, 2, 2, 64, True, None, jnp.float32),  # ragged
+def _all_128_blocks(seq, d_head):
+    """Tiles of 128 (the least a TPU tile takes): several q and kv blocks at
+    test sizes, so dead causal / window blocks are skipped."""
+    from jax.experimental.pallas.ops.tpu.splash_attention import BlockSizes
+    return BlockSizes(block_q=128, block_kv=128, block_kv_compute=128,
+                      block_q_dkv=128, block_kv_dkv=128, block_kv_dkv_compute=128,
+                      block_q_dq=128, block_kv_dq=128)
+
+
+def _flash_case(*vals, tiles=None):
+    """One case, its id spelled from its values (``-t128`` where the test
+    sets 128-row tiles)."""
+    name = "-".join(getattr(v, "__name__", str(v)) for v in vals)
+    return pytest.param(*vals, tiles, id=name + ("" if tiles is None else f"-t{tiles}"))
+
+
+@pytest.mark.parametrize("B,Sq,Skv,H,Kv,dh,causal,window,dt,tiles", [
+    _flash_case(2, 128, 128, 4, 2, 64, True, None, jnp.float32),
+    _flash_case(1, 96, 96, 4, 4, 64, True, 32, jnp.float32),
+    _flash_case(2, 64, 192, 4, 1, 128, True, None, jnp.float32),  # Sq != Skv: dispatch
+    _flash_case(1, 128, 128, 2, 2, 64, False, None, jnp.float32),
+    _flash_case(1, 128, 128, 4, 2, 64, True, None, jnp.bfloat16),
+    _flash_case(1, 100, 100, 2, 2, 64, True, None, jnp.float32),  # ragged
+    _flash_case(1, 384, 384, 8, 2, 128, True, None, jnp.float32, tiles=128),  # GQA, skipping
+    _flash_case(1, 256, 256, 4, 4, 128, True, None, jnp.float32),  # MHA
+    _flash_case(2, 256, 256, 4, 1, 128, True, None, jnp.bfloat16),  # MQA, bf16
+    _flash_case(1, 384, 384, 4, 1, 256, True, 100, jnp.float32, tiles=128),  # window skipping
+    _flash_case(1, 300, 300, 4, 2, 128, True, None, jnp.bfloat16, tiles=128),  # S % block != 0
+    _flash_case(1, 200, 200, 2, 2, 128, False, None, jnp.float32, tiles=128),  # padded keys
 ])
-def test_flash_attention(B, Sq, Skv, H, Kv, dh, causal, window, dt):
-    ks = jax.random.split(jax.random.key(B * Sq + H), 3)
+def test_flash_attention(monkeypatch, B, Sq, Skv, H, Kv, dh, causal, window, dt, tiles):
+    """The flash kernel (interpret mode) against the float32 reference:
+    the forward and dQ/dK/dV. A call it does not take (Sq != Skv) is sent
+    to the chunked path by the dispatch, and matches the reference there."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import ops
+    from repro.nn.attention import AttnCfg, multi_head_attention
+    from repro.obs.metrics import MetricsRegistry
+
+    ks = jax.random.split(jax.random.key(B * Sq + H), 4)
     q = jax.random.normal(ks[0], (B, Sq, H, dh), dt)
     k = jax.random.normal(ks[1], (B, Skv, Kv, dh), dt)
     v = jax.random.normal(ks[2], (B, Skv, Kv, dh), dt)
-    got = flash_attention(q, k, v, causal=causal, window=window, interpret=True,
-                          tile_q=64, tile_k=64)
-    want = ref.flash_attention_ref(q, k, v, causal=causal, window=window)
+    ct = jax.random.normal(ks[3], (B, Sq, H, dh), jnp.float32)
+    if tiles is not None:
+        monkeypatch.setattr(fa, "block_sizes", _all_128_blocks)
+    if Sq == Skv:
+        attend = functools.partial(flash_attention, causal=causal, window=window,
+                                   interpret=True)
+    else:
+        reg = MetricsRegistry()
+        monkeypatch.setattr(ops, "_METRICS", reg)
+        monkeypatch.setattr(ops, "on_tpu", lambda: True)
+        cfg = AttnCfg(n_heads=H, n_kv=Kv, d_head=dh, causal=causal, window=window,
+                      q_chunk=32, kv_chunk=32)
+        assert cfg.impl == "pallas"
+        # right-aligned queries, as the reference places them
+        attend = functools.partial(multi_head_attention, cfg=cfg, q_offset=Skv - Sq)
+    f32 = lambda x: x.astype(jnp.float32)
+
+    def loss(fn):
+        return lambda q, k, v: jnp.sum(f32(fn(q, k, v)) * ct)
+
+    ref_fn = lambda q, k, v: ref.flash_attention_ref(f32(q), f32(k), f32(v),
+                                                      causal=causal, window=window)
+    got = jax.jit(attend)(q, k, v)
+    want = ref_fn(q, k, v)
+    dgot = jax.jit(jax.grad(loss(attend), argnums=(0, 1, 2)))(q, k, v)
+    dwant = jax.grad(loss(ref_fn), argnums=(0, 1, 2))(q, k, v)
+    if Sq != Skv:
+        assert reg.counter("kernels.flash.fallback").value >= 1
+        assert reg.counter("kernels.flash.dispatch").value == 0
+    assert got.dtype == dt
     tol = 3e-2 if dt == jnp.bfloat16 else 3e-4
-    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want, np.float32),
+    np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(want),
                                rtol=tol, atol=tol)
+    for a, b in zip(dgot, dwant):
+        assert a.dtype == dt
+        err = float(jnp.linalg.norm(f32(a) - b) / jnp.linalg.norm(b))
+        assert err < (1e-2 if dt == jnp.bfloat16 else 1e-5), err

@@ -97,6 +97,32 @@ def test_layer_is_the_innermost_name_through_transforms():
     assert scopes.layer_of("jit(step_fn)/jit(stack)/concatenate") == (None, "")
 
 
+def test_table_reads_an_instruction_that_spans_lines():
+    """A kernel's attributes may hold line breaks (the splash attention
+    kernels' ``kernel_metadata``): the ``op_name`` on a later line still
+    names the instruction's layer, and the next instruction keeps its own."""
+    hlo = "\n".join([
+        "HloModule jit_step, entry_computation_layout={()->()}",
+        "",
+        "ENTRY %main.1 (p0: bf16[8,128]) -> bf16[8,128] {",
+        "  %p0 = bf16[8,128]{1,0} parameter(0)",
+        "  %splash_mqa_dq_no_residuals.1 = bf16[8,128]{1,0} custom-call(%p0), "
+        "custom_call_target=\"tpu_custom_call\", frontend_attributes={kernel_metadata={",
+        '"xprof_metadata":"{\\"block_q_dq\\": 512}"',
+        '}}, metadata={op_name="jit(step_fn)/transpose(jvp(stack))/checkpoint/attn/'
+        'vmap(jit(_splash_attention))/splash_mqa_dq_no_residuals/pallas_call"}',
+        '  ROOT %add.2 = bf16[8,128]{1,0} add(%splash_mqa_dq_no_residuals.1, %p0), '
+        'metadata={op_name="jit(step_fn)/optim/add"}',
+        "}",
+        "",
+    ])
+    module, table = scopes.op_layer_table(hlo)
+    assert module == "jit_step"
+    assert table["splash_mqa_dq_no_residuals.1"] == ("attn", "stack/attn")
+    assert table["add.2"] == ("optim", "optim")
+    assert table["p0"] == (None, "")
+
+
 def test_table_survives_a_persistent_cache_hit(tmp_path):
     from jax.experimental.compilation_cache import compilation_cache as cc
 

@@ -1,4 +1,4 @@
-"""The Pallas kernels compiled for a described TPU v5e at Gemma3-1B widths.
+"""The Pallas kernels compiled for a described TPU v5e at real widths.
 
 Nothing runs: each test lowers a kernel against shapes placed on one chip of
 a described ``v5e:2x2`` topology and compiles it with the TPU compiler that
@@ -10,12 +10,15 @@ default 16 MiB limit. The dispatcher-agreement tests pin
 Site shapes (Gemma3-1B, N = 4096 tokens, bf16, block 128, budget 0.2):
 MLP up/gate G [N, 6912] -> dX width 1152 (54 blocks, 11 kept); attention q
 G [N, 1024] -> 1152 (2 kept); attention o G [N, 1152] -> 1024 (2 kept).
+Flash attention, forward and backward, at S = N: Yi-6B (32 heads, 4 KV,
+d_head 128), OLMoE-1B-7B (16 heads MHA) and a Gemma3-1B local layer.
 
 The topology is described inside a module fixture, never at import time:
 only one process may load the TPU library, and every test worker imports
 this file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -112,14 +115,57 @@ def test_col_scores_compiles_at_gemma_mlp_site(one_chip):
     assert "tpu_custom_call" in hlo
 
 
+def _attention_grad_hlo(one_chip, H, Kv, dh, window=None):
+    """HLO of the flash kernel's forward and backward (the gradient of a
+    loss of its output) for one row of N tokens, compiled for one described
+    v5e, inside the ``attn`` scope as ``nn.attention.attention`` calls it."""
+    from repro.obs import scopes
+
+    @scopes.scoped(scopes.ATTN)
+    def attend(q, k, v):
+        return flash_attention(q, k, v, causal=True, window=window)
+
+    def loss(q, k, v):
+        return jnp.sum(attend(q, k, v).astype(jnp.float32))
+
+    sds = lambda shape: jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
+    return _compile(jax.grad(loss, argnums=(0, 1, 2)), sds((1, N, H, dh)),
+                    sds((1, N, Kv, dh)), sds((1, N, Kv, dh))).as_text()
+
+
+def _f32_score_tiles(hlo: str) -> list:
+    """Float32 buffers of the HLO with two axes of 512 or more: a tile of
+    scores or probabilities, query rows by key columns. (A count of
+    elements cannot tell one: at Yi's widths H * d_head = S, so a q-shaped
+    float32 buffer such as the lane-broadcast logsumexp holds S x S.)"""
+    return [dims for dims in re.findall(r"\bf32\[([\d,]*)\]", hlo)
+            if sum(int(d) >= 512 for d in dims.split(",") if d) >= 2]
+
+
+def _check_flash_compile(hlo: str) -> None:
+    """Forward, dQ and dKV kernels are in the compiled program, no score
+    tile reaches HBM, and the op table labels every kernel ``attn``."""
+    from repro.obs.scopes import op_layer_table
+
+    assert "tpu_custom_call" in hlo
+    kernels = set(re.findall(r"%(splash_mqa_(?:fwd|dq|dkv)[\w.]*)\s*=", hlo))
+    for phase in ("fwd", "dq", "dkv"):
+        assert any(name.startswith(f"splash_mqa_{phase}") for name in kernels), kernels
+    assert not _f32_score_tiles(hlo)
+    _, table = op_layer_table(hlo)
+    assert all(table[name][0] == "attn" for name in kernels)
+
+
+@pytest.mark.parametrize("H,Kv", [(32, 4), (16, 16)], ids=["yi-6b", "olmoe"])
+def test_flash_attention_fwd_bwd_compiles(one_chip, H, Kv):
+    """At S 4096, d_head 128: Yi-6B (GQA, 8 query heads a KV head) and
+    OLMoE-1B-7B (MHA)."""
+    _check_flash_compile(_attention_grad_hlo(one_chip, H, Kv, 128))
+
+
 def test_flash_attention_compiles_at_gemma_local_layer(one_chip):
     # 4 query heads sharing 1 kv head (MQA), d_head 256, sliding window 512
-    S = lambda shape: jax.ShapeDtypeStruct(shape, BF16, sharding=one_chip)
-    hlo = _compile(lambda q, k, v: flash_attention(q, k, v, causal=True,
-                                                   window=512),
-                   S((1, N, 4, 256)), S((1, N, 1, 256)),
-                   S((1, N, 1, 256))).as_text()
-    assert "tpu_custom_call" in hlo
+    _check_flash_compile(_attention_grad_hlo(one_chip, 4, 1, 256, window=512))
 
 
 # Dispatcher agreement. d = 1280 needs no padding, so the kernel's outputs
