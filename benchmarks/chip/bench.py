@@ -6,14 +6,35 @@ and a traffic mix; each lives in a file of its own, found by name:
 the correctness check in ``limits/<workload>.json``. Per-layer metrics are
 read by ``metrics/<metric>.py``. Adding a cell or a metric adds files.
 
+A configuration names its plain reference, ``<reference>.py`` beside the
+configurations' directory, which is the one place that knows the
+architecture. Adding a configuration adds its file and, for a new
+architecture, a reference module that exports:
+
+- ``Model.from_config(conf)``: the model's sizes, from the file's keys;
+- ``weight_specs(model)``: {name: (shape, dtype, std)} of every weight;
+- ``weight_key(seed)``, ``make_leaf(key, name, spec)``,
+  ``make_weights(key, specs)``: the weights, drawn on the device;
+- ``program_paths(model)``: {name: path} of each weight's leaf in the
+  program's parameter tree, for exactly the names of ``weight_specs``; a
+  path may lead anywhere in the tree (any segment or sub-layer, or an
+  unstacked leaf such as ``("shared", "attn", "q", "w")``);
+- ``FAULT_LEAF``: the weight whose gradient the planted ``answer`` fault
+  doubles;
+- ``model_flops_per_token(conf, seq)``: the exact model's training FLOPs
+  per token, the yardstick of ``mfu`` and ``step_mfu``;
+- ``Sketch.from_traffic(estimator)`` and ``Reference(model, sketch,
+  optimizer, precision).train(weights, batches, step_keys)``: the plain
+  training steps that ``correct`` compares with.
+
 A run drives the program's front door: one ``Runtime`` and one compiled
 train step, fed by the seeded token stream. Set-up makes the weights on the
 device from the seed, takes the first ``check_steps`` steps through
 ``Runtime.train`` (which compiles), and a few more to size the window. The
 window is one ``Runtime.train`` call of as many steps as fill ``--seconds``,
 ended by ``block_until_ready``. After the window the program's state is
-freed and the plain reference (``reference_lm.py``) trains the first steps
-again from the same weights and batches; ``correct`` compares the two.
+freed and the plain reference trains the first steps again from the same
+weights and batches; ``correct`` compares the two.
 """
 from __future__ import annotations
 
@@ -33,28 +54,6 @@ import numpy as np
 HERE = os.path.dirname(os.path.abspath(__file__))
 ROOT = os.path.dirname(os.path.dirname(HERE))
 
-# canonical weight name -> path of the program's parameter leaf (the
-# layer stack is segment 0, sub-block 0: every layer of these models alike)
-PROGRAM_PATHS = {
-    "embed": ("embed",),
-    "final_norm": ("final_norm", "g"),
-    "lm_head": ("lm_head", "w"),
-    "norm1": ("segments", 0, 0, "norm1", "g"),
-    "norm2": ("segments", 0, 0, "norm2", "g"),
-    "attn_q": ("segments", 0, 0, "attn", "q", "w"),
-    "attn_k": ("segments", 0, 0, "attn", "k", "w"),
-    "attn_v": ("segments", 0, 0, "attn", "v", "w"),
-    "attn_o": ("segments", 0, 0, "attn", "o", "w"),
-    "mlp_in": ("segments", 0, 0, "mlp", "in", "w"),
-    "mlp_gate": ("segments", 0, 0, "mlp", "gate", "w"),
-    "mlp_out": ("segments", 0, 0, "mlp", "out", "w"),
-    "router": ("segments", 0, 0, "moe", "router", "w"),
-    "expert_in": ("segments", 0, 0, "moe", "wi"),
-    "expert_gate": ("segments", 0, 0, "moe", "wg"),
-    "expert_out": ("segments", 0, 0, "moe", "wo"),
-}
-
-
 class NoChip(RuntimeError):
     pass
 
@@ -67,6 +66,7 @@ class Cell:
     traffic: dict
     limits: dict
     per_layer: list      # the BENCHMARK.json per-layer metric entries of this cell
+    here: str = HERE     # the directory its files were found in
 
 
 def _load_json(path: str) -> dict:
@@ -86,7 +86,7 @@ def find_cell(workload: str, root: str = ROOT, here: str = HERE) -> Cell:
     traffic = _load_json(os.path.join(here, "traffic", w["traffic"] + ".json"))
     limits = _load_json(os.path.join(here, "limits", workload + ".json"))
     per_layer = [m for m in spec["per_layer"] if workload in m.get("workloads", [workload])]
-    return Cell(workload, int(w["chips"]), conf, traffic, limits, per_layer)
+    return Cell(workload, int(w["chips"]), conf, traffic, limits, per_layer, here)
 
 
 def load_reader(metric: str) -> Callable:
@@ -97,8 +97,9 @@ def load_reader(metric: str) -> Callable:
     return mod.read
 
 
-def load_reference(conf: dict):
-    path = os.path.join(HERE, conf["reference"] + ".py")
+def load_reference(conf: dict, here: str = HERE):
+    """The reference module the configuration names, from ``here``."""
+    path = os.path.join(here, conf["reference"] + ".py")
     spec = importlib.util.spec_from_file_location(f"chip_ref_{conf['reference']}", path)
     mod = importlib.util.module_from_spec(spec)
     sys.modules[spec.name] = mod
@@ -119,19 +120,20 @@ def peaks(device_kind: str) -> dict:
 
 
 def arch_config(conf: dict):
-    """The program's ArchConfig for a configuration file."""
+    """The program's ArchConfig for a configuration file: sizes from its
+    Hugging Face keys, then its ``program`` entries, which override them."""
     from repro.configs.base import ArchConfig
 
-    prog = dict(conf["program"])
     heads = conf["num_attention_heads"]
-    return ArchConfig(
+    fields = dict(
         name=conf["name"], n_layers=conf["num_hidden_layers"],
         d_model=conf["hidden_size"], n_heads=heads,
-        n_kv=conf.get("num_key_value_heads", heads), d_ff=conf["intermediate_size"],
-        vocab=conf["vocab_size"], rope_theta=conf["rope_theta"],
-        tie_embeddings=conf["tie_word_embeddings"],
-        n_experts=conf.get("num_experts", 0), top_k=conf.get("num_experts_per_tok", 0),
-        **prog)
+        n_kv=conf.get("num_key_value_heads", heads), d_head=conf.get("head_dim"),
+        d_ff=conf["intermediate_size"], vocab=conf["vocab_size"],
+        rope_theta=conf["rope_theta"], tie_embeddings=conf["tie_word_embeddings"],
+        n_experts=conf.get("num_experts", 0), top_k=conf.get("num_experts_per_tok", 0))
+    fields.update(conf["program"])
+    return ArchConfig(**fields)
 
 
 def policy(traffic: dict):
@@ -175,21 +177,26 @@ def _set(tree, path, value):
 class Program:
     """One Runtime, optimizer and compiled step: the system under test."""
 
-    def __init__(self, cell: Cell, *, traced: bool, opt_factory=optimizer):
+    def __init__(self, cell: Cell, *, traced: bool, fault: Optional[str] = None):
         import jax
         from repro.api import ExecutionConfig, ObsConfig, Runtime
 
         self.cell = cell
         self.cfg = arch_config(cell.config)
-        self.opt = opt_factory(cell.traffic)
+        self.ref = load_reference(cell.config, cell.here)
+        self.model = self.ref.Model.from_config(cell.config)
+        self.specs = self.ref.weight_specs(self.model)
+        self.names = [n for n in self.specs]
+        self.paths = self.ref.program_paths(self.model)
+        if set(self.paths) != set(self.specs):
+            raise ValueError(f"program_paths names {sorted(set(self.paths) ^ set(self.specs))} "
+                             f"that weight_specs does not, or the reverse")
+        self.opt = (optimizer(cell.traffic) if fault is None else
+                    faulty_optimizer(cell.traffic, fault, self.paths[self.ref.FAULT_LEAF]))
         obs = ObsConfig(trace=traced, annotate=traced, metrics=True,
                         compile_ledger=False, memory_ledger=False, flight=False)
         self.runtime = Runtime(policy=policy(cell.traffic),
                                execution=ExecutionConfig(obs=obs))
-        self.ref = load_reference(cell.config)
-        self.model = self.ref.Model.from_config(cell.config)
-        self.specs = self.ref.weight_specs(self.model)
-        self.names = [n for n in self.specs]
         self._norm = jax.jit(jnp_norm)
         self._delta = {}
 
@@ -206,7 +213,7 @@ class Program:
             params = st.params
             covered = set()
             for n in self.names:
-                path = PROGRAM_PATHS[n]
+                path = self.paths[n]
                 leaf = _get(params, path)
                 w = self.ref.make_leaf(wkey, n, self.specs[n])
                 if leaf.shape != w.shape or leaf.dtype != w.dtype:
@@ -249,11 +256,11 @@ class Program:
 
     def grad1_norms(self, state) -> dict:
         b1 = self.cell.traffic["optimizer"]["b1"]
-        return {n: float(self._norm(_get(state.opt_state["m"], PROGRAM_PATHS[n]))) / (1 - b1)
+        return {n: float(self._norm(_get(state.opt_state["m"], self.paths[n]))) / (1 - b1)
                 for n in self.names}
 
     def params(self, state) -> dict:
-        return {n: _get(state.params, PROGRAM_PATHS[n]) for n in self.names}
+        return {n: _get(state.params, self.paths[n]) for n in self.names}
 
     def delta_norms(self, seed: int, params: dict) -> dict:
         """Per leaf ||params - initial weights||, the initial weights drawn
@@ -423,8 +430,7 @@ def start(cell: Cell, seed: int, *, trace: bool = False, fault: Optional[str] = 
         # every program of a run, small ones too, is found in the cache next time
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     tr = cell.traffic
-    opt_factory = optimizer if fault is None else (lambda t: faulty_optimizer(t, fault))
-    prog = Program(cell, traced=trace, opt_factory=opt_factory)
+    prog = Program(cell, traced=trace, fault=fault)
     stream = tokens.TokenStream(cell.config["vocab_size"], seed, **tr["tokens"])
     feed = Feed(stream, int(tr["batch"]), int(tr["seq_len"]),
                 mask_half=(fault == "half_batch"))
@@ -535,7 +541,8 @@ def run(cell: Cell, seed: int, seconds: float, trace: bool, *, t_start: float,
         tokens_per_s = n * B * S / win_s
         metrics = {"tokens_per_s": {"value": tokens_per_s, "unit": "tokens/s"}}
         if require_chip:  # off the chip (tests) there is no peak to share
-            mfu = 100.0 * cost.model_flops_per_token(cell.config, S) * tokens_per_s / (
+            flops = cost.model_flops_per_token(cell.config, S, cell.here)
+            mfu = 100.0 * flops * tokens_per_s / (
                 cell.chips * peaks(device["kind"])["bf16_flops_per_s"])
             metrics["mfu"] = {"value": mfu, "unit": "%"}
         metrics.update(peak_hbm_gb={"value": mem / 1e9, "unit": "GB"},
@@ -567,8 +574,9 @@ class Readings:
     device_kind: str
 
 
-def faulty_optimizer(traffic: dict, fault: str):
-    """The cell's optimizer with a planted fault (tests of the check)."""
+def faulty_optimizer(traffic: dict, fault: str, path: tuple):
+    """The cell's optimizer with a planted fault (tests of the check);
+    ``answer`` doubles the gradient of the leaf at ``path``."""
     import jax
     from repro.optim import Optimizer
 
@@ -577,7 +585,6 @@ def faulty_optimizer(traffic: dict, fault: str):
         return Optimizer(opt.init, lambda g, s, p, step: (p, s))
     if fault == "answer":
         def update(grads, st, params, step):
-            path = PROGRAM_PATHS["attn_o"]
             return opt.update(_set(grads, path, 2 * _get(grads, path)), st, params, step)
         return Optimizer(opt.init, update)
     if fault == "half_batch":

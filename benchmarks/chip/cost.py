@@ -1,5 +1,6 @@
 """Operations and bytes, computed from shapes: the model's FLOPs per token
-(for ``mfu``) and each sketch kernel call's least work (for
+(for ``mfu``, counted by the configuration's reference module), the parts
+of a Llama-family count, and each sketch kernel call's least work (for
 ``sketch_kernel_roofline``)."""
 from __future__ import annotations
 
@@ -29,11 +30,15 @@ def attention_flops_per_token(c: dict, seq: int) -> float:
     return c["num_hidden_layers"] * 3 * 2 * 2 * H * dh * (seq + 1) / 2
 
 
-def model_flops_per_token(c: dict, seq: int) -> float:
-    """The exact model's training FLOPs per token: 6 per matmul parameter
-    plus causal attention. Recomputation and the sketch's savings are not
-    counted, so sketched and exact cells share the yardstick."""
-    return 6 * matmul_params_per_token(c) + attention_flops_per_token(c, seq)
+def model_flops_per_token(c: dict, seq: int, here: str | None = None) -> float:
+    """The exact model's training FLOPs per token, as the reference module
+    that configuration ``c`` names (found in ``here``, the benchmark's
+    directory by default) counts them: 6 per matmul parameter plus the
+    sequence mixer's own work. Recomputation and the sketch's savings are
+    not counted, so sketched and exact cells share the yardstick."""
+    from benchmarks.chip import bench
+
+    return bench.load_reference(c, here or bench.HERE).model_flops_per_token(c, seq)
 
 
 # ---------------------------------------------------------------------------

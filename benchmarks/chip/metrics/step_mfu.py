@@ -11,5 +11,5 @@ def read(r):
         return None
     tr = r.cell.traffic
     tokens = r.steps * tr["batch"] * tr["seq_len"]
-    flops = tokens * cost.model_flops_per_token(r.cell.config, tr["seq_len"])
+    flops = tokens * cost.model_flops_per_token(r.cell.config, tr["seq_len"], r.cell.here)
     return 100.0 * flops / (win * r.cell.chips * bench.peaks(r.device_kind)["bf16_flops_per_s"])

@@ -2,7 +2,10 @@
 or with a top-k mixture of experts, trained by AdamW.
 
 Written from the configuration file's keys (Hugging Face names) and the
-published descriptions; it imports nothing of the program. Every matmul
+published descriptions; it imports nothing of the program. For the harness
+it also names each weight's leaf in the program's parameter tree
+(``program_paths``) and counts the model's FLOPs a token
+(``model_flops_per_token``). Every matmul
 runs at ``precision=HIGHEST``. Parameters are stored in the configuration's
 ``torch_dtype`` between steps, as the configuration states, and computed
 in float32.
@@ -36,12 +39,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from benchmarks.chip import cost
+
 HIGHEST = jax.lax.Precision.HIGHEST
 # role ids of the sketched sites, in the order the estimator numbers them
 ROLE_ID = {"attn_q": 0, "attn_k": 1, "attn_v": 2, "attn_o": 3,
            "mlp_in": 4, "mlp_gate": 5, "mlp_out": 6}
 EXPERT_KEY = 1000
 F8_MAX = 448.0
+# the weight whose gradient the planted ``answer`` fault doubles
+FAULT_LEAF = "attn_o"
+# weight name -> path of the program's parameter leaf (the layer stack is
+# segment 0, sub-block 0: every layer of these models alike)
+PROGRAM_PATHS = {
+    "embed": ("embed",),
+    "final_norm": ("final_norm", "g"),
+    "lm_head": ("lm_head", "w"),
+    "norm1": ("segments", 0, 0, "norm1", "g"),
+    "norm2": ("segments", 0, 0, "norm2", "g"),
+    "attn_q": ("segments", 0, 0, "attn", "q", "w"),
+    "attn_k": ("segments", 0, 0, "attn", "k", "w"),
+    "attn_v": ("segments", 0, 0, "attn", "v", "w"),
+    "attn_o": ("segments", 0, 0, "attn", "o", "w"),
+    "mlp_in": ("segments", 0, 0, "mlp", "in", "w"),
+    "mlp_gate": ("segments", 0, 0, "mlp", "gate", "w"),
+    "mlp_out": ("segments", 0, 0, "mlp", "out", "w"),
+    "router": ("segments", 0, 0, "moe", "router", "w"),
+    "expert_in": ("segments", 0, 0, "moe", "wi"),
+    "expert_gate": ("segments", 0, 0, "moe", "wg"),
+    "expert_out": ("segments", 0, 0, "moe", "wo"),
+}
 
 
 def run_value(c: dict, key: str, default=None):
@@ -128,6 +155,18 @@ def weight_specs(m: Model) -> dict:
                   "mlp_gate": ((L, F, d), dt, d ** -0.5),
                   "mlp_out": ((L, d, F), dt, F ** -0.5)})
     return s
+
+
+def program_paths(m: Model) -> dict:
+    """name -> path of the program's leaf, for every weight of ``m``."""
+    return {n: PROGRAM_PATHS[n] for n in weight_specs(m)}
+
+
+def model_flops_per_token(c: dict, seq: int) -> float:
+    """The exact model's training FLOPs per token: 6 per matmul parameter
+    (every projection, the routed experts and the router, the head; not
+    the embedding lookup) plus causal attention over the mean context."""
+    return 6 * cost.matmul_params_per_token(c) + cost.attention_flops_per_token(c, seq)
 
 
 def weight_key(seed: int):

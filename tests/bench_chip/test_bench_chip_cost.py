@@ -32,6 +32,15 @@ def test_model_flops_per_token_hand_count(name, params, attn):
     assert cost.model_flops_per_token(c, 4096) == pytest.approx(6 * params + attn)
 
 
+@pytest.mark.parametrize("name,flops", [("yi-6b-4l", 6127976448.0),
+                                        ("olmoe-1b-7b-2l", 1525702656.0)])
+def test_model_flops_per_token_is_the_llama_count(name, flops):
+    # mfu's constant for the cells of these configurations, to the last bit
+    c = _conf(name)
+    assert cost.model_flops_per_token(c, 4096) == flops
+    assert flops == 6 * cost.matmul_params_per_token(c) + cost.attention_flops_per_token(c, 4096)
+
+
 def test_yi_flops_per_token_is_6_13_gflop():
     assert cost.model_flops_per_token(_conf("yi-6b-4l"), 4096) == pytest.approx(6.13e9, rel=1e-3)
 

@@ -92,3 +92,49 @@ def test_step_mfu_reads_the_traced_window():
                        host_spans=[], device_kind="TPU v5 lite")
     flops = 4 * 4096 * cost.model_flops_per_token(cell.config, 4096)
     assert bench.load_reader("step_mfu")(r) == pytest.approx(100 * flops / (2.0 * 197e12))
+
+
+# where the program keeps each weight of a Llama-family model: the layer
+# stack is segment 0, sub-block 0
+LLAMA_PATHS = {
+    "embed": ("embed",),
+    "final_norm": ("final_norm", "g"),
+    "lm_head": ("lm_head", "w"),
+    "norm1": ("segments", 0, 0, "norm1", "g"),
+    "norm2": ("segments", 0, 0, "norm2", "g"),
+    "attn_q": ("segments", 0, 0, "attn", "q", "w"),
+    "attn_k": ("segments", 0, 0, "attn", "k", "w"),
+    "attn_v": ("segments", 0, 0, "attn", "v", "w"),
+    "attn_o": ("segments", 0, 0, "attn", "o", "w"),
+    "mlp_in": ("segments", 0, 0, "mlp", "in", "w"),
+    "mlp_gate": ("segments", 0, 0, "mlp", "gate", "w"),
+    "mlp_out": ("segments", 0, 0, "mlp", "out", "w"),
+    "router": ("segments", 0, 0, "moe", "router", "w"),
+    "expert_in": ("segments", 0, 0, "moe", "wi"),
+    "expert_gate": ("segments", 0, 0, "moe", "wg"),
+    "expert_out": ("segments", 0, 0, "moe", "wo"),
+}
+
+
+@pytest.mark.parametrize("config,ffn", [
+    ("yi-6b-4l", ["mlp_in", "mlp_gate", "mlp_out"]),
+    ("olmoe-1b-7b-2l", ["router", "expert_in", "expert_gate", "expert_out"]),
+])
+def test_reference_lm_names_the_programs_leaves(config, ffn):
+    conf = json.load(open(os.path.join(ROOT, "benchmarks", "chip", "configs", config + ".json")))
+    ref = bench.load_reference(conf)
+    m = ref.Model.from_config(conf)
+    names = ["embed", "final_norm", "lm_head", "norm1", "norm2",
+             "attn_q", "attn_k", "attn_v", "attn_o"] + ffn
+    assert ref.program_paths(m) == {n: LLAMA_PATHS[n] for n in names}
+    assert set(ref.program_paths(m)) == set(ref.weight_specs(m))
+    assert ref.FAULT_LEAF == "attn_o"
+
+
+def test_arch_config_takes_head_dim_and_program_overrides():
+    conf = bench.find_cell("yi6b-4l.train4k.exact").config
+    assert bench.arch_config(conf).d_head is None
+    wide = dict(conf, head_dim=256, program=dict(conf["program"], d_ff=4096, n_kv=8))
+    cfg = bench.arch_config(wide)
+    assert (cfg.head_dim, cfg.d_ff, cfg.n_kv) == (256, 4096, 8)
+    assert (cfg.d_model, cfg.n_heads) == (4096, 32)
